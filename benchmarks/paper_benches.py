@@ -261,15 +261,15 @@ def bench_serving_throughput(rows):
     # tensor-parallel row: the headline workload on a forced 2-device host
     # mesh (page pools sharded by kv head over "model"; docs/multi-host.md).
     # Runs in a subprocess because the virtual device count is fixed at
-    # process start. On CPU this measures the TP *overhead* (collectives +
-    # per-shard dispatch on virtual devices), not a speedup — the row
-    # exists so the sharded step's hot path is timed and smoke-checked.
+    # process start, pinned to the CPU (JAX_PLATFORMS=cpu) so it never
+    # reaches for an accelerator the parent process holds. It measures the
+    # TP *overhead* on virtual host devices (collectives + per-shard
+    # dispatch), not a speedup — a host row, labelled platform=cpu_host.
     import os
     import subprocess
     import sys
     tp_code = (
         "import jax, jax.numpy as jnp, numpy as np, time\n"
-        "import repro.compat\n"
         "from repro.config import get_config\n"
         "from repro.serving import InferenceEngine, Request\n"
         "cfg = get_config('glm4_9b', smoke=True)\n"
@@ -288,7 +288,7 @@ def bench_serving_throughput(rows):
         "eng.run(reqs())\n"
         "print('TP2RESULT', time.perf_counter() - t0, sum(max_news))\n"
     )
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
                         + env.get("XLA_FLAGS", "")).strip()
     proc = subprocess.run([sys.executable, "-c", tp_code],
@@ -299,7 +299,8 @@ def bench_serving_throughput(rows):
                 if ln.startswith("TP2RESULT"))
     dt_tp, n_tp = float(line.split()[1]), int(line.split()[2])
     rows.append(_csv("serving/paged_engine_tp2", dt_tp / n_tp * 1e6,
-                     f"tok_s={n_tp/dt_tp:.1f} mesh=model2"))
+                     f"tok_s={n_tp/dt_tp:.1f} mesh=model2 "
+                     "platform=cpu_host"))
 
 
 # ---------------------------------------------------------------------------
@@ -615,9 +616,9 @@ def bench_paged_kernels(rows):
     rng = np.random.default_rng(0)
     B, H, K, hd, bs, nb = 8, 8, 4, 64, 16, 8
     num_blocks = B * nb + 1
-    k_pages = jnp.asarray(rng.normal(0, 1, (num_blocks, bs, K, hd)),
+    k_pages = jnp.asarray(rng.normal(0, 1, (num_blocks, K, bs, hd)),
                           jnp.bfloat16)
-    v_pages = jnp.asarray(rng.normal(0, 1, (num_blocks, bs, K, hd)),
+    v_pages = jnp.asarray(rng.normal(0, 1, (num_blocks, K, bs, hd)),
                           jnp.bfloat16)
     tables = jnp.asarray(
         1 + np.arange(B * nb, dtype=np.int32).reshape(B, nb))

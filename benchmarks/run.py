@@ -19,6 +19,9 @@ import time
 
 def main() -> None:
     from benchmarks import paper_benches as pb
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     args = sys.argv[1:]
     json_out = None

@@ -1,7 +1,4 @@
 """repro: a jax/pallas reproduction of "TensorFlow: A system for
 large-scale machine learning" grown toward a production serving/training
-stack. Importing the package installs jax version-compat shims first so
-every entry point (launch scripts, tests, benchmarks) sees one API.
+stack.
 """
-
-from repro import compat as _compat  # noqa: F401  (installs jax shims)

@@ -93,7 +93,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-37)
         o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[...] = (m_scr[...] + jnp.log(l))[:, 0]
+        lse_ref[...] = m_scr[...] + jnp.log(l)
 
 
 def _flash_fwd(q, k, v, *, causal, window, cap, scale, q_offset,
@@ -135,11 +135,13 @@ def _flash_fwd(q, k, v, *, causal, window, cap, scale, q_offset,
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, hd), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((None, block_q), lambda bh, qi, ki: (bh, qi)),
+            # lse as a (block_q, 1) column: a (block_q,) row block of a
+            # (B*H, S) array is not a legal Mosaic tile
+            pl.BlockSpec((None, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, nq * block_q, hd), q.dtype),
-            jax.ShapeDtypeStruct((B * H, nq * block_q), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, nq * block_q, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -150,7 +152,7 @@ def _flash_fwd(q, k, v, *, causal, window, cap, scale, q_offset,
     )(qb, kb, vb)
 
     o = o.reshape(B, H, nq * block_q, hd).transpose(0, 2, 1, 3)[:, :Sq]
-    lse = lse.reshape(B, H, nq * block_q).transpose(0, 2, 1)[:, :Sq]
+    lse = lse[..., 0].reshape(B, H, nq * block_q).transpose(0, 2, 1)[:, :Sq]
     return o, lse
 
 

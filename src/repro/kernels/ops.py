@@ -7,8 +7,11 @@ Each op has three implementations:
      dry-run lowering;
   3. a naive oracle in ``repro.kernels.ref`` used only by tests.
 
-Dispatch: Pallas on TPU backends (or when ``REPRO_FORCE_PALLAS=interpret`` is
-set, for kernel validation), XLA path otherwise.
+Dispatch: compiled Pallas on TPU backends; off the TPU the XLA path, or the
+Pallas kernels in interpret mode when ``REPRO_FORCE_PALLAS=interpret`` is
+set (kernel validation on CPU). ``REPRO_FORCE_PALLAS`` exists only for that
+validation: on a TPU backend any value of it raises, so nothing can quietly
+route the chip around its kernels.
 """
 
 from __future__ import annotations
@@ -21,12 +24,15 @@ import jax
 def _use_pallas() -> str | None:
     """Returns None (XLA path), "compiled", or "interpret"."""
     force = os.environ.get("REPRO_FORCE_PALLAS", "")
+    if jax.default_backend() == "tpu":
+        if force:
+            raise RuntimeError(
+                f"REPRO_FORCE_PALLAS={force!r} is for validating kernels on "
+                "CPU; on a TPU backend the compiled Pallas kernels always "
+                "run. Unset it.")
+        return "compiled"
     if force == "interpret":
         return "interpret"
-    if force == "off":
-        return None
-    if jax.default_backend() == "tpu":
-        return "compiled"
     return None
 
 

@@ -2,7 +2,10 @@
 
 One query token per sequence attends to a KV cache that lives in fixed-size
 *blocks* scattered through two page pools shaped
-``(num_blocks, block_size, K, hd)``. A per-sequence *block table* names the
+``(num_blocks, K, block_size, hd)`` — head-major, so one page of one kv
+head is a whole ``(block_size, hd)`` tile: Mosaic requires a block's last
+two dims to be tile-aligned or whole, and each grid step DMAs exactly one
+live page of one kv head. A per-sequence *block table* names the
 pool rows holding that sequence's KV, in order; the serving block manager
 (``repro.serving.kv_cache``) owns the tables and the free list.
 
@@ -76,6 +79,14 @@ def _dequant_tile(x, s):
         .astype(jnp.float32)
 
 
+def _live_columns(lives, shape, block_size):
+    """(rows, P * block_size) mask of the score columns whose page is live.
+    Built from an iota: Mosaic cannot lower a concatenate of booleans."""
+    page = jax.lax.broadcasted_iota(jnp.int32, shape, 1) // block_size
+    return functools.reduce(lambda a, c: a | c,
+                            [(page == i) & li for i, li in enumerate(lives)])
+
+
 def _decode_kernel(bt_ref, ctx_ref, mask_ref, q_ref, *rest, scale, cap,
                    window, block_size, num_kv_heads, pages_per_block,
                    table_width, with_lse, with_scales):
@@ -142,9 +153,7 @@ def _decode_kernel(bt_ref, ctx_ref, mask_ref, q_ref, *rest, scale, cap,
         if P > 1:
             # columns of dead pages (past the table, masked out, or wholly
             # past ctx) carry redirected/garbage KV — mask them out
-            col_ok = jnp.concatenate(
-                [jnp.broadcast_to(li, (block_size,)) for li in lives])
-            mask &= col_ok[None, :]
+            mask &= _live_columns(lives, s.shape, block_size)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -193,10 +202,10 @@ def _page_specs(nb, P, K, block_size, hd, n_extra_scalars):
             b = bk // K
             entry = jnp.minimum(j * P + i, nb - 1)
             ok = (j * P + i < nb) & (mask_ref[b, entry] != 0)
-            return (jnp.where(ok, bt_ref[b, entry], 0), 0, bk % K, 0)
+            return (jnp.where(ok, bt_ref[b, entry], 0), bk % K, 0, 0)
         return page_index
 
-    return [pl.BlockSpec((None, block_size, None, hd), mk(i))
+    return [pl.BlockSpec((None, None, block_size, hd), mk(i))
             for i in range(P)]
 
 
@@ -206,7 +215,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
                     pages_per_compute_block=1,
                     k_scale=None, v_scale=None):
     """q: (B, H, hd) one decode token per sequence.
-    k_pages/v_pages: (num_blocks, block_size, K, hd).
+    k_pages/v_pages: (num_blocks, K, block_size, hd).
     block_tables: (B, max_blocks_per_seq) int32 pool-row ids (padding rows
     are ignored past ctx). ctx_lens: (B,) int32 — tokens visible per
     sequence, 0 for an inactive slot (output row is zeros).
@@ -227,13 +236,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     before the stitch would make the result shard-count-dependent). Rows
     that attended nothing return lse <= NEG_INF (zero stitch weight).
 
-    ``k_scale``/``v_scale`` ((num_blocks, block_size, K, 1) fp32) mark a
+    ``k_scale``/``v_scale`` ((num_blocks, K, block_size, 1) fp32) mark a
     quantized pool: each fetched page tile is dequantized in-VMEM (the
     ``quant.dequantize_kv`` bf16 round-trip) before the matmuls — the
     pool itself is never widened.
     """
     B, H, hd = q.shape
-    _, block_size, K, _ = k_pages.shape
+    _, K, block_size, _ = k_pages.shape
     G = H // K
     nb = block_tables.shape[1]
     P = max(1, min(int(pages_per_compute_block), nb))
@@ -290,7 +299,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       block_mask.astype(jnp.int32), qg,
@@ -381,9 +390,7 @@ def _chunk_kernel(bt_ref, ctx_ref, qlen_ref, mask_ref, q_ref, *rest, scale,
         if window is not None:
             mask &= k_pos > q_pos - window
         if P > 1:
-            col_ok = jnp.concatenate(
-                [jnp.broadcast_to(li, (block_size,)) for li in lives])
-            mask &= col_ok[None, :]
+            mask &= _live_columns(lives, s.shape, block_size)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -432,7 +439,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     output is (B, C, H) fp32.
     """
     B, C, H, hd = q.shape
-    _, block_size, K, _ = k_pages.shape
+    _, K, block_size, _ = k_pages.shape
     G = H // K
     nb = block_tables.shape[1]
     P = max(1, min(int(pages_per_compute_block), nb))
@@ -492,7 +499,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       q_lens.astype(jnp.int32), block_mask.astype(jnp.int32),
@@ -654,9 +661,7 @@ def _ragged_kernel(start_ref, end_ref, ctx_ref, bt_ref, q_ref, *rest,
         if P > 1:
             # columns of dead pages (past the table or wholly past ctx)
             # carry redirected/garbage KV — mask them out
-            col_ok = jnp.concatenate(
-                [jnp.broadcast_to(li, (block_size,)) for li in lives])
-            mask &= col_ok[None, :]
+            mask &= _live_columns(lives, s.shape, block_size)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -711,7 +716,7 @@ def ragged_paged_prefill_attention(q, k_pages, v_pages, block_tables,
     scattered into the scale pools (``models.attention`` does both).
     """
     T, H, hd = q.shape
-    _, block_size, K, _ = k_pages.shape
+    _, K, block_size, _ = k_pages.shape
     G = H // K
     S = starts.shape[0]
     nb = block_tables.shape[1]
@@ -733,8 +738,8 @@ def ragged_paged_prefill_attention(q, k_pages, v_pages, block_tables,
             # redirect to pool row 0 (never attended: liveness skips them)
             entry = jnp.minimum(j * P + i, nb - 1)
             ok = (j * P + i < nb) & (entry * block_size < ctx_ref[s])
-            return (jnp.where(ok, bt_ref[s, entry], 0), 0, k, 0)
-        return pl.BlockSpec((None, block_size, None, hd_), idx)
+            return (jnp.where(ok, bt_ref[s, entry], 0), k, 0, 0)
+        return pl.BlockSpec((None, None, block_size, hd_), idx)
 
     kernel = functools.partial(
         _ragged_kernel, scale=scale, cap=cap, window=window,
@@ -751,9 +756,11 @@ def ragged_paged_prefill_attention(q, k_pages, v_pages, block_tables,
     out_shape = [jax.ShapeDtypeStruct((K, T, G, hd), q.dtype)]
     aliases = {}
     if with_write:
-        new_spec = pl.BlockSpec((T, None, hd), lambda k, s, j, *_: (0, k, 0))
+        # (T, K, hd) -> (K, T, hd): one kv head's chunk rows are a whole
+        # (T, hd) block
+        new_spec = pl.BlockSpec((None, T, hd), lambda k, s, j, *_: (k, 0, 0))
         in_specs += [new_spec, new_spec]
-        operands += [k_new, v_new]
+        operands += [k_new.transpose(1, 0, 2), v_new.transpose(1, 0, 2)]
         out_specs += [page_specs[0], page_specs[0]]
         out_shape += [jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
                       jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)]
@@ -784,7 +791,7 @@ def ragged_paged_prefill_attention(q, k_pages, v_pages, block_tables,
         out_shape=tuple(out_shape) if with_write else out_shape[0],
         interpret=interpret,
         input_output_aliases=aliases,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(starts.astype(jnp.int32), ends.astype(jnp.int32),
       ctx_lens.astype(jnp.int32), block_tables.astype(jnp.int32),

@@ -11,14 +11,16 @@ from repro.models import quant
 
 
 def _gather_pages(pages, scale, block_tables):
-    """Densify a page pool through the block table; a quantized pool
+    """Densify a head-major page pool ``(N, K, block_size, hd)`` through
+    the block table into ``(B, nb * block_size, K, hd)``; a quantized pool
     (per-row scale supplied) dequantizes right after the gather — the
     bf16 round-trip in ``quant.dequantize_kv`` is the same one the
     kernels apply in-tile, so both paths attend identical operands."""
-    g = pages[block_tables]
+    g = pages[block_tables]                         # (B, nb, K, bs, hd)
     if scale is not None:
         g = quant.dequantize_kv(g, scale[block_tables])
-    return g
+    B, nb, K, bs, hd = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, nb * bs, K, hd)
 
 
 def attention_ref(q, k, v, *, causal=True, window=None, cap=None, scale=None,
@@ -53,18 +55,18 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens, *,
     """Paged decode attention oracle: densify the block-table gather, then
     the exact masked-softmax math of ``models.attention._decode_attn_local``.
 
-    q: (B, H, hd); pages: (num_blocks, block_size, K, hd);
+    q: (B, H, hd); pages: (num_blocks, K, block_size, hd);
     block_tables: (B, nb) int32; ctx_lens: (B,) int32 (0 => zero output).
-    k_scale/v_scale: optional (num_blocks, block_size, K, 1) fp32 per-row
+    k_scale/v_scale: optional (num_blocks, K, block_size, 1) fp32 per-row
     scales for a quantized pool (dequantized after the gather).
     """
     B, H, hd = q.shape
-    _, bs, K, _ = k_pages.shape
+    _, K, bs, _ = k_pages.shape
     G = H // K
     scale = hd ** -0.5 if scale is None else scale
-    # densify: (B, nb, bs, K, hd) -> (B, S, K, hd), S = nb * bs
-    k = _gather_pages(k_pages, k_scale, block_tables).reshape(B, -1, K, hd)
-    v = _gather_pages(v_pages, v_scale, block_tables).reshape(B, -1, K, hd)
+    # densify: (B, S, K, hd), S = nb * bs
+    k = _gather_pages(k_pages, k_scale, block_tables)
+    v = _gather_pages(v_pages, v_scale, block_tables)
     S = k.shape[1]
     qg = q.reshape(B, G, K, hd)
     logits = jnp.einsum("bgkh,bskh->bgks", qg, k,
@@ -104,11 +106,11 @@ def paged_attention_partial_ref(q, k_pages, v_pages, block_tables, ctx_lens,
     order) before the final q.dtype cast.
     """
     B, H, hd = q.shape
-    _, bs, K, _ = k_pages.shape
+    _, K, bs, _ = k_pages.shape
     G = H // K
     scale = hd ** -0.5 if scale is None else scale
-    k = _gather_pages(k_pages, k_scale, block_tables).reshape(B, -1, K, hd)
-    v = _gather_pages(v_pages, v_scale, block_tables).reshape(B, -1, K, hd)
+    k = _gather_pages(k_pages, k_scale, block_tables)
+    v = _gather_pages(v_pages, v_scale, block_tables)
     S = k.shape[1]
     qg = q.reshape(B, G, K, hd)
     logits = jnp.einsum("bgkh,bskh->bgks", qg, k,
@@ -185,11 +187,11 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens,
     oracle above.
     """
     B, C, H, hd = q.shape
-    _, bs, K, _ = k_pages.shape
+    _, K, bs, _ = k_pages.shape
     G = H // K
     scale = hd ** -0.5 if scale is None else scale
-    k = _gather_pages(k_pages, k_scale, block_tables).reshape(B, -1, K, hd)
-    v = _gather_pages(v_pages, v_scale, block_tables).reshape(B, -1, K, hd)
+    k = _gather_pages(k_pages, k_scale, block_tables)
+    v = _gather_pages(v_pages, v_scale, block_tables)
     S = k.shape[1]
     qg = q.reshape(B, C, G, K, hd)
     logits = jnp.einsum("bcgkh,bskh->bcgks", qg, k,
@@ -231,13 +233,12 @@ def ragged_paged_prefill_attention_ref(q, k_pages, v_pages, block_tables,
     with B == 1.
     """
     T, H, hd = q.shape
-    _, bs, K, _ = k_pages.shape
+    _, K, bs, _ = k_pages.shape
     G = H // K
     S = starts.shape[0]
     scale = hd ** -0.5 if scale is None else scale
-    k = _gather_pages(k_pages, k_scale,
-                      block_tables).reshape(S, -1, K, hd)  # (S, E, K, hd)
-    v = _gather_pages(v_pages, v_scale, block_tables).reshape(S, -1, K, hd)
+    k = _gather_pages(k_pages, k_scale, block_tables)      # (S, E, K, hd)
+    v = _gather_pages(v_pages, v_scale, block_tables)
     E = k.shape[1]
     qg = q.reshape(T, G, K, hd)
     logits = jnp.einsum("tgkh,sekh->tgkse", qg, k,

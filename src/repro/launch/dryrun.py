@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat as _compat  # noqa: F401  (jax API shims)
 from repro.config import (ARCHS, SHAPES, OptimizerConfig, ParallelConfig,
                           get_config, shape_applicable)
 from repro.launch.mesh import make_production_mesh

@@ -4,8 +4,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat as _compat  # noqa: F401  (jax API shims)
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The assigned production mesh: one pod = 16x16 = 256 chips; the

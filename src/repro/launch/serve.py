@@ -51,8 +51,8 @@ import time
 
 import numpy as np
 
-from repro import compat as _compat  # noqa: F401  (jax API shims)
 from repro.config import get_config
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def poisson_arrival_steps(n: int, rate: float, rng) -> list[int]:
@@ -125,6 +125,33 @@ def build_controller(args, n_replicas: int = 1):
                                n_replicas=n_replicas)
 
 
+def device_summary() -> dict:
+    """The device the run is on, as JAX reports it."""
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def peak_bytes_in_use():
+    """Device 0's peak allocation so far, or None where the backend
+    reports no memory statistics (the CPU)."""
+    import jax
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("peak_bytes_in_use") if stats else None
+
+
+def check_completed(reqs, outs) -> None:
+    """Raise unless every request produced exactly its own ``max_new``
+    tokens (only valid when no EOS id or stop sequence can end it early)."""
+    short = {r.rid: (len(outs.get(r.rid, ())), r.max_new) for r in reqs
+             if len(outs.get(r.rid, ())) != r.max_new}
+    if short:
+        raise RuntimeError(
+            f"requests did not complete with their own max_new tokens "
+            f"(rid: (got, max_new)): {short}")
+
+
 def make_requests(cfg, args, rng):
     from repro.serving import Request
     from repro.serving.scheduler import SamplingParams
@@ -172,6 +199,16 @@ async def _drive(eng, controller, reqs, arrivals):
 
 def run_engine(cfg, mesh, args):
     eng = build_engine(cfg, mesh, args)
+    print(f"[serve] engine built: arch={cfg.name} layers={cfg.num_layers} "
+          f"d_model={cfg.d_model} peak_bytes_in_use={peak_bytes_in_use()}",
+          flush=True)
+    return serve_workload(eng, cfg, mesh, args)
+
+
+def serve_workload(eng, cfg, mesh, args):
+    """The synthetic Poisson workload through one engine and the
+    front-end; prints the summary lines and returns {rid: tokens}. Raises
+    if a request ends short of its own ``max_new`` tokens."""
     controller = build_controller(args)
     rng = np.random.default_rng(args.seed)
     reqs = make_requests(cfg, args, rng)
@@ -219,6 +256,10 @@ def run_engine(cfg, mesh, args):
               f"spec_decodes={s['spec_decodes']} "
               f"mean_accept_len={eng.mean_accept_len:.3f}")
     print("[serve] sample output ids:", outs[reqs[0].rid][:8].tolist())
+    if args.eos_id is None and not args.stop:
+        check_completed(reqs, outs)
+    print(f"[serve] device={device_summary()} requests_completed="
+          f"{s['requests_done']}/{len(reqs)}")
     return outs
 
 
@@ -327,7 +368,7 @@ def run_http(cfg, mesh, args):
                             host or "127.0.0.1", int(port)))
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="glm4_9b")
     ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
@@ -435,7 +476,13 @@ def main():
                     "generated tokens (max_new still wins)")
     ap.add_argument("--eos-id", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
+    return ap
+
+
+def main():
+    ap = build_parser()
     args = ap.parse_args()
+    configure_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     from repro.launch.mesh import make_host_mesh
     data, model = parse_mesh(args.mesh)

@@ -19,30 +19,38 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat as _compat  # noqa: F401  (jax API shims)
 from repro.config import (OptimizerConfig, ParallelConfig, ShapeConfig,
                           get_config)
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.checkpoint.elastic import restore_for_mesh, save_global
 from repro.data.pipeline import Pipeline, ShardedSource
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import api
 from repro.optim import optimizers as opt
 from repro.spmd import steps as steps_mod
 
 
 def build_state(cfg, pcfg, ocfg, mesh, seed=0):
+    """Initialise the bf16 working params and the optimizer state (which
+    holds the only fp32 copy, as master weights) in one jitted call that
+    writes each leaf straight into its sharding — no host-side fp32 tree,
+    no second fp32 copy beside the master."""
+    def init(key):
+        params_f32, _ = api.init_model(cfg, key)
+        state = opt.init_train_state(ocfg, params_f32)
+        params = jax.tree.map(lambda x: x.astype(jnp.bfloat16),
+                              state["master"])
+        return params, state
+
     with jax.set_mesh(mesh):
-        params_f32, specs = api.init_model(cfg, jax.random.key(seed))
-        opt_state = opt.init_train_state(ocfg, params_f32)
-        params = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params_f32)
-        psh = steps_mod.resolve_param_shardings(params, specs, cfg, pcfg,
-                                                mesh)
-        osh = steps_mod.opt_state_shardings(
-            jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                         opt_state),
-            params_f32, specs, cfg, pcfg, mesh)
-        params = jax.tree.map(jax.device_put, params, psh)
-        opt_state = jax.tree.map(jax.device_put, opt_state, osh)
+        shapes_f32, specs = api.abstract_params(cfg)
+        params_shape, opt_shape = jax.eval_shape(init, jax.random.key(seed))
+        psh = steps_mod.resolve_param_shardings(params_shape, specs, cfg,
+                                                pcfg, mesh)
+        osh = steps_mod.opt_state_shardings(opt_shape, shapes_f32, specs,
+                                            cfg, pcfg, mesh)
+        params, opt_state = jax.jit(init, out_shardings=(psh, osh))(
+            jax.random.key(seed))
     return params, opt_state, specs, psh, osh
 
 
@@ -96,7 +104,10 @@ def train(cfg, *, steps, batch, seq, mesh, pcfg=None, ocfg=None,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="glm4_9b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced-width config (--no-smoke: published "
+                         "widths)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -105,6 +116,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     d, m = (int(x) for x in args.mesh.split(","))

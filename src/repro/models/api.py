@@ -26,6 +26,18 @@ def init_model(cfg: ModelConfig, key):
     return transformer.init_lm(cfg, key)
 
 
+def init_params_bf16(cfg: ModelConfig, key):
+    """Random bf16 weights in one jitted program: every leaf is drawn in
+    fp32 and cast inside it, so the fp32 tree never exists as a whole —
+    for a 3B-parameter model that tree alone would be 12 GB beside the
+    6 GB bf16 one."""
+    def init(key):
+        params, _ = init_model(cfg, key)
+        return jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
+
+    return jax.jit(init)(key)
+
+
 def loss_fn(params, batch, cfg: ModelConfig, pcfg: ParallelConfig,
             sampled_ids=None):
     if is_encdec(cfg):

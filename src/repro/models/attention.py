@@ -297,42 +297,45 @@ def update_cache(cache, new, pos):
             cache, new, pos)
 
 
+# A head-major page pool (num_blocks, K, block_size, hd) sharded by kv head.
+POOL_SPEC = P(None, "model", None, None)
+
+
 def _constrain_pool(pages):
     """Keep a page pool sharded by kv head across the scatter update —
     without the constraint GSPMD is free to replicate the (large) pools
     between the KV write and the shard_map'd attention read."""
-    tp, mesh = _paged_tp(pages.shape[2])
+    tp, mesh = _paged_tp(pages.shape[1])
     if tp == 1:
         return pages
     return jax.lax.with_sharding_constraint(
-        pages, jax.sharding.NamedSharding(
-            mesh, P(None, None, "model", None)))
+        pages, jax.sharding.NamedSharding(mesh, POOL_SPEC))
 
 
 def update_paged_cache(pages, new, block_tables, pos):
     """Scatter one new KV row per sequence into its block-table page.
 
-    pages: (num_blocks, block_size, K, hd); new: (B, 1, K, hd); pos: (B,)
+    pages: (num_blocks, K, block_size, hd); new: (B, 1, K, hd); pos: (B,)
     absolute write position. Inactive serving slots carry an all-zero table
     row, so their writes land in the reserved trash block 0 (never allocated
     to a request) and corrupt nothing.
     """
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     block_ids = jnp.take_along_axis(
         block_tables, (pos // bs)[:, None], axis=1)[:, 0]     # (B,)
     return _constrain_pool(
-        pages.at[block_ids, pos % bs].set(new[:, 0].astype(pages.dtype)))
+        pages.at[block_ids, :, pos % bs].set(new[:, 0].astype(pages.dtype)))
 
 
 def update_paged_cache_chunk(pages, new, block_tables, q_start, q_lens):
     """Scatter a chunk of new KV rows per sequence into its pages.
 
-    pages: (num_blocks, block_size, K, hd); new: (B, C, K, hd); q_start:
+    pages: (num_blocks, K, block_size, hd); new: (B, C, K, hd); q_start:
     (B,) absolute position of chunk row 0; q_lens: (B,) valid rows. Rows
     past q_lens are routed to the reserved trash block 0 (never allocated
     to a request), like an idle decode slot's write.
     """
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     B, C = new.shape[:2]
     nb = block_tables.shape[1]
     pos = q_start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]   # (B, C)
@@ -341,7 +344,7 @@ def update_paged_cache_chunk(pages, new, block_tables, q_start, q_lens):
     valid = jnp.arange(C)[None] < q_lens[:, None]
     blk = jnp.where(valid, blk, 0)                  # trash the padding rows
     return _constrain_pool(
-        pages.at[blk.reshape(-1), (pos % bs).reshape(-1)].set(
+        pages.at[blk.reshape(-1), :, (pos % bs).reshape(-1)].set(
             new.reshape(B * C, *new.shape[2:]).astype(pages.dtype)))
 
 
@@ -349,7 +352,7 @@ def update_paged_cache_ragged(pages, new, block_tables, ctx_lens, starts,
                               ends, row_seq):
     """Scatter a packed (ragged) multi-sequence chunk of KV into pages.
 
-    pages: (num_blocks, block_size, K, hd); new: (1, T, K, hd) — chunks of
+    pages: (num_blocks, K, block_size, hd); new: (1, T, K, hd) — chunks of
     up to S sequences packed back to back; sequence s owns flat rows
     [starts[s], ends[s]) and row_seq maps each flat row to its owner. Flat
     row t lands at absolute position ``ctx_lens[s] - (ends[s] - starts[s])
@@ -359,7 +362,7 @@ def update_paged_cache_ragged(pages, new, block_tables, ctx_lens, starts,
     destination rows, so the pool contents match the single-chunk path
     bit for bit.
     """
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     T = new.shape[1]
     nb = block_tables.shape[1]
     t = jnp.arange(T, dtype=jnp.int32)
@@ -369,7 +372,7 @@ def update_paged_cache_ragged(pages, new, block_tables, ctx_lens, starts,
     idx = jnp.clip(pos // bs, 0, nb - 1)
     blk = jnp.where(valid, block_tables[row_seq, idx], 0)
     return _constrain_pool(
-        pages.at[blk, pos % bs].set(new[0].astype(pages.dtype)))
+        pages.at[blk, :, pos % bs].set(new[0].astype(pages.dtype)))
 
 
 def replicate_over_model(x):
@@ -420,7 +423,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     """
     from repro.kernels import ops as kops
     B, _, H, hd = q.shape
-    K = k_pages.shape[2]
+    K = k_pages.shape[1]
     scale = hd ** -0.5 if scale is None else scale
     tp, mesh = _paged_tp(K)
     if tp == 1:
@@ -433,7 +436,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     qg = q[:, 0].reshape(B, G, K, hd)         # g-major; see dense_attention
 
     def body(qg, kp, vp, bt, ctx, *scales):
-        K_l = kp.shape[2]
+        K_l = kp.shape[1]
         ks, vs = scales if scales else (None, None)
         o = kops.paged_attention(qg.reshape(B, G * K_l, hd), kp, vp, bt,
                                  ctx, window=window, cap=cap, scale=scale,
@@ -441,12 +444,13 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         return o.reshape(B, G, K_l, hd)
 
     extra = (k_scale, v_scale) if k_scale is not None else ()
-    kv_spec = P(None, None, "model", None)    # rank-4, kv heads at axis 2
     o = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(kv_spec, kv_spec, kv_spec, P(None, None), P(None),
-                  *([kv_spec] * len(extra))),
+        in_specs=(P(None, None, "model", None), POOL_SPEC, POOL_SPEC,
+                  P(None, None), P(None), *([POOL_SPEC] * len(extra))),
         out_specs=P(None, None, "model", None),
+        # the paged kernels' pallas_call outputs carry no varying-axes type
+        check_vma=False,
     )(qg, k_pages, v_pages, block_tables, ctx_lens, *extra)
     return replicate_over_model(o).reshape(B, 1, H, hd).astype(q.dtype)
 
@@ -461,7 +465,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     :func:`paged_decode_attention` when the mesh allows."""
     from repro.kernels import ops as kops
     B, C, H, hd = q.shape
-    K = k_pages.shape[2]
+    K = k_pages.shape[1]
     scale = hd ** -0.5 if scale is None else scale
     tp, mesh = _paged_tp(K)
     if tp == 1:
@@ -474,7 +478,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     qg = q.reshape(B, C, G, K, hd)            # g-major; see dense_attention
 
     def body(qg, kp, vp, bt, ctx, qlen, *scales):
-        K_l = kp.shape[2]                     # (nb, bs, K_l, hd)
+        K_l = kp.shape[1]                     # (nb, K_l, bs, hd)
         ks, vs = scales if scales else (None, None)
         o = kops.paged_prefill_attention(
             qg.reshape(B, C, G * K_l, hd), kp, vp, bt, ctx, qlen,
@@ -482,13 +486,14 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         return o.reshape(B, C, G, K_l, hd)
 
     extra = (k_scale, v_scale) if k_scale is not None else ()
-    kv_spec = P(None, None, "model", None)
     o = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, None, "model", None),
-                  kv_spec, kv_spec, P(None, None), P(None),
-                  P(None), *([kv_spec] * len(extra))),
+                  POOL_SPEC, POOL_SPEC, P(None, None), P(None),
+                  P(None), *([POOL_SPEC] * len(extra))),
         out_specs=P(None, None, None, "model", None),
+        # the paged kernels' pallas_call outputs carry no varying-axes type
+        check_vma=False,
     )(qg, k_pages, v_pages, block_tables, ctx_lens, q_lens, *extra)
     return replicate_over_model(o).reshape(B, C, H, hd).astype(q.dtype)
 
@@ -535,6 +540,14 @@ def paged_shard_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     return o.astype(q.dtype)
 
 
+def _dense_pages(pages, block_tables):
+    """Gather a head-major pool (N, K, block_size, t) through the block
+    table into the dense per-sequence layout (B, nb * block_size, K, t)."""
+    g = pages[block_tables]                           # (B, nb, K, bs, t)
+    B, nb, K, bs, t = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, nb * bs, K, t)
+
+
 def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, ctx_lens,
                               q_lens, *, window=None, cap=None, scale=None,
                               k_scale=None, v_scale=None):
@@ -552,14 +565,14 @@ def paged_chunk_attention_xla(q, k_pages, v_pages, block_tables, ctx_lens,
     so attention operands are bit-identical across paths).
     """
     B, C, H, hd = q.shape
-    _, bs, K, _ = k_pages.shape
+    K = k_pages.shape[1]
     G = H // K
     scale = hd ** -0.5 if scale is None else scale
-    k = k_pages[block_tables].reshape(B, -1, K, hd)
-    v = v_pages[block_tables].reshape(B, -1, K, hd)
+    k = _dense_pages(k_pages, block_tables)
+    v = _dense_pages(v_pages, block_tables)
     if k_scale is not None:
-        k = quant.dequantize_kv(k, k_scale[block_tables].reshape(B, -1, K, 1))
-        v = quant.dequantize_kv(v, v_scale[block_tables].reshape(B, -1, K, 1))
+        k = quant.dequantize_kv(k, _dense_pages(k_scale, block_tables))
+        v = quant.dequantize_kv(v, _dense_pages(v_scale, block_tables))
     S = k.shape[1]
     qg = q.reshape(B, C, G, K, hd)
     logits = jnp.einsum("bqgkh,bskh->bgkqs", qg, k,
@@ -615,7 +628,7 @@ def ragged_chunk_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     :func:`paged_chunk_attention` when the mesh allows."""
     from repro.kernels import ops as kops
     _, T, H, hd = q.shape
-    K = k_pages.shape[2]
+    K = k_pages.shape[1]
     scale = hd ** -0.5 if scale is None else scale
     tp, mesh = _paged_tp(K)
     if tp == 1:
@@ -628,7 +641,7 @@ def ragged_chunk_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     qg = q[0].reshape(T, G, K, hd)            # g-major; see dense_attention
 
     def body(qg, kp, vp, bt, ctx, st, en, rs, *scales):
-        K_l = kp.shape[2]
+        K_l = kp.shape[1]
         ks, vs = scales if scales else (None, None)
         o = kops.ragged_paged_prefill_attention(
             qg.reshape(T, G * K_l, hd), kp, vp, bt, ctx, st, en, rs,
@@ -636,12 +649,14 @@ def ragged_chunk_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         return o.reshape(T, G, K_l, hd)
 
     extra = (k_scale, v_scale) if k_scale is not None else ()
-    kv_spec = P(None, None, "model", None)
     o = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(kv_spec, kv_spec, kv_spec, P(None, None), P(None),
-                  P(None), P(None), P(None), *([kv_spec] * len(extra))),
+        in_specs=(P(None, None, "model", None), POOL_SPEC, POOL_SPEC,
+                  P(None, None), P(None), P(None), P(None), P(None),
+                  *([POOL_SPEC] * len(extra))),
         out_specs=P(None, None, "model", None),
+        # the paged kernels' pallas_call outputs carry no varying-axes type
+        check_vma=False,
     )(qg, k_pages, v_pages, block_tables, ctx_lens, starts, ends, row_seq,
       *extra)
     return replicate_over_model(o).reshape(1, T, H, hd).astype(q.dtype)
@@ -668,7 +683,7 @@ def ragged_chunk_update_attend(q, k_new, v_new, k_pages, v_pages,
     the dequant). Returns ``(o, k_pages, v_pages, k_scale, v_scale)``.
     """
     from repro.kernels import ops as kops
-    K = k_pages.shape[2]
+    K = k_pages.shape[1]
     tp, _ = _paged_tp(K)
     if k_scale is not None:
         kvd = quant.kv_dtype_name(k_pages.dtype)

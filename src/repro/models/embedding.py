@@ -74,6 +74,8 @@ def embed(table, tokens, cfg: ModelConfig):
         body, mesh=mesh,
         in_specs=(P("model", None), P(dps, None)),
         out_specs=P(dps, None, None),
+        # the gather kernel's pallas_call output carries no varying-axes type
+        check_vma=False,
     )(table, tokens)
     out = out.astype(jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32)
     if cfg.embedding_scale:

@@ -11,10 +11,11 @@ pages, and the prefix-cache / COW / preemption byte-identity story
 survives quantization unchanged (equivalence vs bf16 itself is
 tolerance-based, pinned by tests).
 
-Scale layout: pool leaf (NP, num_blocks, block_size, K, hd) gets a
-scale leaf (NP, num_blocks, block_size, K, 1) in fp32 — rank-5 with
-num_blocks at axis 1, so the engine's block-indexed copy/COW/swap
-helpers treat value and scale leaves uniformly.
+Scale layout: pool leaf (NP, num_blocks, K, block_size, hd) gets a
+scale leaf (NP, num_blocks, K, block_size, 1) in fp32 — rank-5 with
+num_blocks at axis 1 and kv heads at axis 2, so the engine's
+block-indexed copy/COW/swap helpers and the kv-head sharding treat value
+and scale leaves uniformly (``serving.kv_cache.pool_shape``).
 
 Dequantization always round-trips through bf16 — (q.f32 * scale).bf16 —
 before entering the attention matmuls, in kernels, XLA mirrors and

@@ -169,7 +169,7 @@ def _attn_decode(bp, x, cfg: ModelConfig, ctx, cache, kind: str):
 
 def _attn_decode_paged(bp, x, cfg: ModelConfig, ctx, cache, kind: str):
     """One-token attention against a block-paged KV cache (serving engine).
-    cache: {"k","v"} page pools (num_blocks, block_size, K, hd), plus
+    cache: {"k","v"} page pools (num_blocks, K, block_size, hd), plus
     {"k_scale","v_scale"} fp32 per-row scale pools when quantized — the
     new row quantizes before the scatter (no bf16 pool copy) and the
     dequant is fused into the attention kernel."""
@@ -206,7 +206,7 @@ def _attn_chunk_paged(bp, x, cfg: ModelConfig, ctx, cache, kind: str):
     """Chunked-prefill attention against a block-paged KV cache: scatter
     this chunk's KV into the pages, then attend the chunk's queries
     causally over the whole paged context (prior chunks included).
-    cache: {"k","v"} page pools (num_blocks, block_size, K, hd)."""
+    cache: {"k","v"} page pools (num_blocks, K, block_size, hd)."""
     window = cfg.sliding_window if kind == "local" else None
     h = apply_norm(bp["norm"], x, cfg)
     q = project_q(bp["attn"], h, cfg, ctx["cos_sin"])

@@ -242,15 +242,11 @@ class InferenceEngine:
 
         with jax.set_mesh(mesh):
             if params is None:
-                params_f32, _ = api.init_model(cfg, jax.random.key(seed))
-                params = jax.tree.map(
-                    lambda x: x.astype(jnp.bfloat16), params_f32)
+                params = api.init_params_bf16(cfg, jax.random.key(seed))
             if draft_cfg is not None:
                 if draft_params is None:
-                    dp_f32, _ = api.init_model(draft_cfg,
-                                               jax.random.key(seed + 1))
-                    draft_params = jax.tree.map(
-                        lambda x: x.astype(jnp.bfloat16), dp_f32)
+                    draft_params = api.init_params_bf16(
+                        draft_cfg, jax.random.key(seed + 1))
                 params = {"tgt": self._place_params(params, cfg),
                           "dft": self._place_params(draft_params,
                                                     draft_cfg)}
@@ -406,6 +402,18 @@ class InferenceEngine:
         speculation is off / no speculative decode has run yet."""
         n = self.stats["spec_decodes"]
         return self.stats["spec_emitted"] / n if n else 0.0
+
+    def lower_steps(self) -> dict:
+        """Lower the two plain step executables — with and without a
+        prefill chunk — on this engine's own parameters, cache and array
+        shapes, without running them: ``{"chunk": Lowered, "plain":
+        Lowered}``, for inspecting what the compiled program contains."""
+        arrays = self._build_arrays(StepPlan([], [], []), False)
+        with jax.set_mesh(self.mesh):
+            return {"chunk": self._step_chunk.lower(self.params, self.cache,
+                                                    arrays),
+                    "plain": self._step_plain.lower(self.params, self.cache,
+                                                    arrays)}
 
     # -- jitted bodies -----------------------------------------------------
 

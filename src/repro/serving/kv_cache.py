@@ -1,11 +1,12 @@
 """Block-table KV-cache management for continuous-batching serving.
 
 The device side is a pytree of page pools, one {"k","v"} pair per scanned
-layer stack, each shaped ``(NP, num_blocks, block_size, K, hd)`` — the
-vLLM layout with this repo's layer-stacked leading dim. Every layer uses
-the *same* block ids (one table per sequence, all layers), so allocating a
-block grants one ``block_size``-token slice of KV capacity across the whole
-model at once.
+layer stack, each shaped ``(NP, num_blocks, K, block_size, hd)`` —
+head-major pages, so one kv head's page is a whole ``(block_size, hd)``
+tile that the Pallas kernels fetch as one block (``pool_shape``;
+docs/kv-cache.md). Every layer uses the *same* block ids (one table per
+sequence, all layers), so allocating a block grants one
+``block_size``-token slice of KV capacity across the whole model at once.
 
 The host side is ``BlockManager``: a refcounted allocator with per-request
 block tables plus a content-hash index for prefix caching:
@@ -99,6 +100,16 @@ def mamba_layer_stacks(cfg: ModelConfig) -> list[str]:
     return [f"sub{i}" for i, k in enumerate(kinds) if k == "mamba"]
 
 
+def pool_shape(n_layers: int, num_blocks: int, block_size: int,
+               cfg: ModelConfig, width: int | None = None) -> tuple:
+    """Shape of one layer-stacked page pool: ``(n_layers, num_blocks, K,
+    block_size, width)`` with width = head_dim for value pools, 1 for the
+    per-row scale pools of a quantized cache. Block ids index axis 1 and
+    kv heads axis 2 for every pool leaf."""
+    return (n_layers, num_blocks, cfg.num_kv_heads, block_size,
+            cfg.head_dim if width is None else width)
+
+
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                      dtype=jnp.bfloat16, kv_dtype: str = "bf16"):
     """Zero page pools matching ``transformer.decode_step_paged``.
@@ -109,14 +120,14 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
 
     With a quantized ``kv_dtype`` ("int8" / "fp8") the k/v leaves store
     the narrow dtype and each stack gains fp32 ``k_scale`` / ``v_scale``
-    leaves shaped ``(NP, num_blocks, block_size, K, 1)`` — same rank and
+    leaves shaped ``(NP, num_blocks, K, block_size, 1)`` — same rank and
     block axis as the pools, so block-indexed copy/COW/swap helpers
     handle value and scale leaves uniformly (docs/kv-cache.md)."""
     kinds, NP = period_structure(cfg)
-    shape = (NP, num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
+    shape = pool_shape(NP, num_blocks, block_size, cfg)
     if quant.is_quantized(kv_dtype):
         dtype = quant.KV_DTYPES[kv_dtype]
-    sshape = shape[:-1] + (1,)
+    sshape = pool_shape(NP, num_blocks, block_size, cfg, width=1)
 
     def stack():
         c = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}

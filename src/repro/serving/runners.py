@@ -52,7 +52,7 @@ from repro.config import ModelConfig, ParallelConfig
 from repro.models import encdec, transformer
 from repro.serving.cache import init_encoder_cache, init_slot_state
 from repro.serving.kv_cache import (init_paged_cache, attn_layer_stacks,
-                                    mamba_layer_stacks)
+                                    mamba_layer_stacks, pool_shape)
 from repro.serving.sampling import (SP_KEYS, propose_tokens,
                                     propose_tokens_full, sample_tokens,
                                     sample_tokens_full, speculative_verify,
@@ -276,8 +276,7 @@ class EncDecRunner(ModelRunner):
                 f"kv_dtype={kv_dtype}: the enc-dec runner keeps bf16 pools "
                 "(cross K/V is per-slot, not paged)")
         cfg = self.cfg
-        shape = (cfg.num_layers, num_blocks, block_size,
-                 cfg.num_kv_heads, cfg.head_dim)
+        shape = pool_shape(cfg.num_layers, num_blocks, block_size, cfg)
         return {"self": {"k": jnp.zeros(shape, jnp.bfloat16),
                          "v": jnp.zeros(shape, jnp.bfloat16)},
                 "cross": init_encoder_cache(cfg, max_batch)}

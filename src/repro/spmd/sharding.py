@@ -130,7 +130,7 @@ def serving_tp(mesh) -> int:
 
 
 def paged_pool_pspec(num_kv_heads: int, tp: int) -> P:
-    """Spec for a page-pool stack (NP, num_blocks, block_size, K, hd).
+    """Spec for a page-pool stack (NP, num_blocks, K, block_size, hd).
 
     Pools shard over "model" by *whole kv heads* — the one pool dim whose
     slices are self-contained (every query group of a kv head attends only
@@ -146,16 +146,18 @@ def paged_pool_pspec(num_kv_heads: int, tp: int) -> P:
             f"model axis ({tp}): page pools shard by whole kv heads. "
             "Choose a model-axis size that divides num_kv_heads, or shard "
             "the blocks axis via the LSE-stitch path (docs/multi-host.md).")
-    return P(None, None, None, "model" if tp > 1 else None, None)
+    return P(None, None, "model" if tp > 1 else None, None, None)
 
 
 def serving_cache_pspec(path, leaf, tp: int) -> P:
     """Spec for one serving-cache leaf, keyed on the cache pytree path.
 
-    * paged pools / encoder K-V (dict leaves "k"/"v"/"xk"/"xv", 5D with kv
-      heads on axis 3) shard by kv head — per-head attention over them is
-      computed entirely on the owning shard and gathered before any
-      cross-head contraction, so outputs stay bitwise mesh-invariant;
+    * paged pools and their quantization scales (dict leaves "k"/"v"/
+      "k_scale"/"v_scale", 5D with kv heads on axis 2) and encoder K-V
+      ("xk"/"xv", 5D with kv heads on axis 3) shard by kv head — per-head
+      attention over them is computed entirely on the owning shard and
+      gathered before any cross-head contraction, so outputs stay bitwise
+      mesh-invariant;
     * Mamba slot-state tuples (conv tail, ssm state) stay **replicated**:
       they are constant-size per slot (nothing grows with context), and
       storing the recurrent state sharded lets GSPMD propagate that
@@ -167,15 +169,14 @@ def serving_cache_pspec(path, leaf, tp: int) -> P:
     if tp <= 1:
         return P()
     keys = [k.key for k in path if isinstance(k, jax.tree_util.DictKey)]
-    if keys and keys[-1] in ("k", "v", "xk", "xv") and leaf.ndim == 5:
-        ok = leaf.shape[3] % tp == 0
-        return P(None, None, None, "model" if ok else None, None)
-    if keys and keys[-1] in ("k_scale", "v_scale") and leaf.ndim == 5:
-        # quantized-pool scale leaves (NP, nb, bs, K, 1): same kv-head
-        # sharding as the value pools they describe
-        ok = leaf.shape[3] % tp == 0
-        return P(None, None, None, "model" if ok else None, None)
-    return P()
+    axis = {"k": 2, "v": 2, "k_scale": 2, "v_scale": 2,
+            "xk": 3, "xv": 3}.get(keys[-1] if keys else None)
+    if axis is None or leaf.ndim != 5:
+        return P()
+    spec = [None] * 5
+    if leaf.shape[axis] % tp == 0:
+        spec[axis] = "model"
+    return P(*spec)
 
 
 def serving_cache_shardings(cache, mesh):

@@ -5,8 +5,6 @@ must see the real single CPU device; multi-device tests spawn subprocesses
 import jax
 import pytest
 
-import repro.compat  # noqa: F401  (installs jax version shims for all tests)
-
 
 @pytest.fixture(scope="session")
 def tiny_mesh():

@@ -57,7 +57,6 @@ def test_async_save_then_restore(tmp_path):
 
 
 ELASTIC_CODE = """
-import repro.compat
 import jax, jax.numpy as jnp, numpy as np
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.checkpoint.elastic import restore_for_mesh, save_global

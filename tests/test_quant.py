@@ -69,9 +69,9 @@ def test_dtype_helpers_roundtrip():
 
 def _quant_case(name, B, K, hd, bs, nblk):
     N = 1 + B * nblk
-    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(jnp.bfloat16)
-    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(jnp.bfloat16)
     qk, sk = quantize_kv(kp, name)
     qv, sv = quantize_kv(vp, name)

@@ -40,9 +40,9 @@ def _ragged_case(S, H, K, hd, bs, nblk, dtype, lens, pad=0):
     T = int(sum(lens)) + pad
     N = 1 + S * nblk
     q = jnp.asarray(RNG.normal(0, 1, (T, H, hd)), jnp.float32).astype(dtype)
-    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
-    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
     perm = RNG.permutation(np.arange(1, N))[:S * nblk].reshape(S, nblk)
     bt = jnp.asarray(perm, jnp.int32)
@@ -161,9 +161,9 @@ def test_ragged_fused_write_matches_separate_scatter():
 def _paged_case(B, H, K, hd, bs, nblk, dtype):
     N = 1 + B * nblk
     q = jnp.asarray(RNG.normal(0, 1, (B, H, hd)), jnp.float32).astype(dtype)
-    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
-    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
     perm = RNG.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
     bt = jnp.asarray(perm, jnp.int32)
@@ -189,8 +189,10 @@ def test_prefill_kernel_multipage_vs_ref(P):
     B, H, K, hd, bs, nblk, C = 2, 6, 2, 16, 8, 5, 20
     N = 1 + B * nblk
     q = jnp.asarray(RNG.normal(0, 1, (B, C, H, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)), jnp.float32)
+    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
+                     jnp.float32)
+    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
+                     jnp.float32)
     perm = RNG.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
     bt = jnp.asarray(perm, jnp.int32)
     qlen = np.array([C, C // 2])

@@ -27,9 +27,9 @@ def _paged_case(B, H, K, hd, bs, nblk, dtype):
     """Random page pools + disjoint per-seq block tables + ctx lens."""
     N = 1 + B * nblk
     q = jnp.asarray(RNG.normal(0, 1, (B, H, hd)), jnp.float32).astype(dtype)
-    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
-    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
     perm = RNG.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
     bt = jnp.asarray(perm, jnp.int32)
@@ -69,10 +69,10 @@ def test_paged_ref_vs_dense_oracle(case):
                                          cap=cap), np.float32)
     for b in range(B):
         S = int(ctx[b])
-        k = np.asarray(kp, np.float32)[np.asarray(bt[b])].reshape(
-            -1, K, hd)[:S]
-        v = np.asarray(vp, np.float32)[np.asarray(bt[b])].reshape(
-            -1, K, hd)[:S]
+        k = np.asarray(kp, np.float32)[np.asarray(bt[b])].transpose(
+            0, 2, 1, 3).reshape(-1, K, hd)[:S]
+        v = np.asarray(vp, np.float32)[np.asarray(bt[b])].transpose(
+            0, 2, 1, 3).reshape(-1, K, hd)[:S]
         o_d = attention_ref(
             jnp.asarray(q[b:b + 1, None], jnp.float32),
             jnp.asarray(k[None]), jnp.asarray(v[None]),
@@ -100,9 +100,9 @@ def _chunk_case(B, H, K, hd, bs, nblk, C, dtype):
     N = 1 + B * nblk
     q = jnp.asarray(RNG.normal(0, 1, (B, C, H, hd)),
                     jnp.float32).astype(dtype)
-    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
-    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
     perm = RNG.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
     bt = jnp.asarray(perm, jnp.int32)
@@ -160,10 +160,10 @@ def test_chunk_ref_vs_dense_oracle():
         if n == 0:
             assert np.all(o_p[b] == 0)
             continue
-        k = np.asarray(kp, np.float32)[np.asarray(bt[b])].reshape(
-            -1, K, hd)[:S]
-        v = np.asarray(vp, np.float32)[np.asarray(bt[b])].reshape(
-            -1, K, hd)[:S]
+        k = np.asarray(kp, np.float32)[np.asarray(bt[b])].transpose(
+            0, 2, 1, 3).reshape(-1, K, hd)[:S]
+        v = np.asarray(vp, np.float32)[np.asarray(bt[b])].transpose(
+            0, 2, 1, 3).reshape(-1, K, hd)[:S]
         o_d = attention_ref(
             jnp.asarray(q[b:b + 1, :n], jnp.float32),
             jnp.asarray(k[None]), jnp.asarray(v[None]),

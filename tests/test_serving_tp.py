@@ -47,9 +47,9 @@ RNG = np.random.default_rng(7)
 def _case(B, H, K, hd, bs, nblk, dtype=jnp.float32):
     N = 1 + B * nblk
     q = jnp.asarray(RNG.normal(0, 1, (B, H, hd)), jnp.float32).astype(dtype)
-    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
-    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)),
+    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
     perm = RNG.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
     bt = jnp.asarray(perm, jnp.int32)
@@ -221,8 +221,8 @@ def test_chunk_ref_unchanged_by_full_mask_path():
 def test_paged_pool_pspec_and_error_path():
     from jax.sharding import PartitionSpec as P
     assert paged_pool_pspec(4, 1) == P(None, None, None, None, None)
-    assert paged_pool_pspec(4, 2) == P(None, None, None, "model", None)
-    assert paged_pool_pspec(4, 4) == P(None, None, None, "model", None)
+    assert paged_pool_pspec(4, 2) == P(None, None, "model", None, None)
+    assert paged_pool_pspec(4, 4) == P(None, None, "model", None, None)
     for K, tp in ((2, 4), (3, 2), (1, 2), (6, 4)):
         with pytest.raises(ValueError, match="not divisible"):
             paged_pool_pspec(K, tp)
@@ -245,13 +245,13 @@ def test_serving_cache_pspec_by_leaf_kind():
     its bitwise mesh-invariance (see serving_cache_pspec docstring)."""
     from jax.sharding import PartitionSpec as P
     from jax.tree_util import DictKey, SequenceKey
-    pool = jnp.zeros((2, 9, 8, 4, 16))
+    pool = jnp.zeros((2, 9, 4, 8, 16))          # head-major pages
     enc = jnp.zeros((2, 4, 15, 4, 16))
     state = jnp.zeros((2, 4, 8, 16, 8))
     tail = jnp.zeros((2, 4, 3, 24))
     kpath = (DictKey("sub0"), DictKey("k"))
     assert serving_cache_pspec(kpath, pool, 2) \
-        == P(None, None, None, "model", None)
+        == P(None, None, "model", None, None)
     assert serving_cache_pspec((DictKey("cross"), DictKey("xk")), enc, 2) \
         == P(None, None, None, "model", None)
     # kv heads (4) don't divide tp=3: replicated storage
@@ -431,7 +431,6 @@ def test_slot_cache_walk_mesh_invariant(mesh_model):
 TP_CODE = """
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-import repro.compat
 from repro.config import get_config
 from repro.models import api
 from repro.serving import InferenceEngine, Request
@@ -543,7 +542,6 @@ def test_engine_tp_byte_identical_subprocess():
 
 TP_FAMILY_CODE = """
 import jax, jax.numpy as jnp, numpy as np
-import repro.compat
 from repro.config import get_config
 from repro.models import api
 from repro.serving import InferenceEngine, Request

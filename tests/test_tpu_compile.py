@@ -1,0 +1,120 @@
+"""Compile the serving path's Pallas kernels for a TPU v5e at starcoder2_3b's
+published widths, without a chip.
+
+Interpret mode (every other kernel test) never applies Mosaic's tiling rules;
+this file does: each kernel is lowered and compiled for a described v5e and
+the compiled program must hold the kernel (``tpu_custom_call``). Nothing
+runs, so these tests say nothing about results — the interpret-mode tests
+and ``chip_smoke.py`` do.
+
+The topology is described inside a module fixture, never at import: only one
+process at a time may load the TPU compiler library, and it keeps it until
+it exits.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.config import get_config
+from repro.kernels import embedding as emb
+from repro.kernels import flash_attention as fa
+from repro.kernels import paged_attention as pa
+
+CFG = get_config("starcoder2_3b", smoke=False)
+H, K, HD = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
+# the serving defaults chip_smoke.py runs: block_size 16, max_len 2048,
+# max_batch 8, a 32-token prefill chunk; a ragged pack of 4 in 128 rows
+BS, NB, B, C, T, S = 16, 128, 8, 32, 128, 4
+N = B * NB + 1
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.experimental import topologies
+    was = jax.config.jax_enable_compilation_cache
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"   # else libtpu logs under /tmp
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:      # no TPU compiler here: nothing to test
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+        if log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+        else:
+            os.environ["TPU_LOG_DIR"] = log_dir
+
+
+def _decode(kv_dtype):
+    with_scales = kv_dtype != jnp.bfloat16
+
+    def f(q, kp, vp, bt, ctx, *scales):
+        ks, vs = scales if with_scales else (None, None)
+        return pa.paged_attention(q, kp, vp, bt, ctx, k_scale=ks,
+                                  v_scale=vs)
+
+    shapes = [((B, H, HD), jnp.bfloat16), ((N, K, BS, HD), kv_dtype),
+              ((N, K, BS, HD), kv_dtype), ((B, NB), jnp.int32),
+              ((B,), jnp.int32)]
+    if with_scales:
+        shapes += [((N, K, BS, 1), jnp.float32)] * 2
+    return f, shapes
+
+
+def _ragged(fused):
+    def f(q, kp, vp, bt, ctx, starts, ends, *new):
+        k_new, v_new = new if fused else (None, None)
+        return pa.ragged_paged_prefill_attention(
+            q, kp, vp, bt, ctx, starts, ends, k_new=k_new, v_new=v_new)
+
+    shapes = [((T, H, HD), jnp.bfloat16), ((N, K, BS, HD), jnp.bfloat16),
+              ((N, K, BS, HD), jnp.bfloat16), ((S, NB), jnp.int32),
+              ((S,), jnp.int32), ((S,), jnp.int32), ((S,), jnp.int32)]
+    if fused:
+        shapes += [((T, K, HD), jnp.bfloat16)] * 2
+    return f, shapes
+
+
+KERNELS = {
+    "paged_decode": lambda: _decode(jnp.bfloat16),
+    "paged_decode_int8": lambda: _decode(jnp.int8),
+    "paged_chunked_prefill": lambda: (
+        pa.paged_prefill_attention,
+        [((1, C, H, HD), jnp.bfloat16), ((N, K, BS, HD), jnp.bfloat16),
+         ((N, K, BS, HD), jnp.bfloat16), ((1, NB), jnp.int32),
+         ((1,), jnp.int32), ((1,), jnp.int32)]),
+    "ragged_prefill": lambda: _ragged(False),
+    "ragged_prefill_fused_write": lambda: _ragged(True),
+    "embedding_gather": lambda: (
+        emb.gather,
+        [((CFG.padded_vocab_size, CFG.d_model), jnp.bfloat16),
+         ((B, C), jnp.int32)]),
+    "flash_forward": lambda: (
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+        [((1, 512, H, HD), jnp.bfloat16), ((1, 512, K, HD), jnp.bfloat16),
+         ((1, 512, K, HD), jnp.bfloat16)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(name, one_chip):
+    fn, shapes = KERNELS[name]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
