@@ -48,6 +48,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.config import ModelConfig, ParallelConfig
 from repro.models import api, quant
@@ -58,7 +59,8 @@ from repro.serving.runners import make_runner
 from repro.serving.sampling import SamplingBuffer
 from repro.serving.scheduler import (Request, SamplingParams, Scheduler,
                                      StepPlan, SwapCostModel)
-from repro.serving.stats import Histogram, SECONDS_BUCKETS, STEP_BUCKETS
+from repro.serving.stats import (Histogram, SECONDS_BUCKETS, STEP_BUCKETS,
+                                 StepRecord)
 from repro.spmd import sharding as shd
 
 __all__ = ["InferenceEngine", "Request", "SamplingParams"]
@@ -94,6 +96,15 @@ def pack_ragged(rows: list[np.ndarray], width: int,
         ends[i] = off + n
         off += n
     return tok, seq, starts, ends
+
+
+def _jit_step(name: str, step_fn, **kw):
+    """``step_fn`` with ``kw`` bound, jitted under a stable name: the
+    compiled module is ``jit_<name>``, which is what a profile's XLA
+    Modules line shows for it. The cache (argument 1) is donated."""
+    fn = functools.partial(step_fn, **kw)
+    fn.__name__ = name
+    return jax.jit(fn, donate_argnums=(1,))
 
 
 def unpack_ragged(tok: np.ndarray, starts: np.ndarray, ends: np.ndarray,
@@ -261,12 +272,10 @@ class InferenceEngine:
                     self.cache, shd.serving_cache_shardings(self.cache,
                                                             mesh))
 
-        self._step_chunk = jax.jit(
-            functools.partial(self.runner.step, has_chunk=True),
-            donate_argnums=(1,))
-        self._step_plain = jax.jit(
-            functools.partial(self.runner.step, has_chunk=False),
-            donate_argnums=(1,))
+        self._step_chunk = _jit_step("serve_step_chunk", self.runner.step,
+                                     has_chunk=True)
+        self._step_plain = _jit_step("serve_step_decode", self.runner.step,
+                                     has_chunk=False)
         # full-sampling executables are built LAZILY: a deployment that
         # never sees a top-p/penalty/logprobs request never compiles (or
         # traces) the full pipeline — the pure-greedy fast-path guard
@@ -335,7 +344,8 @@ class InferenceEngine:
                       "swapped_out_bytes": 0, "swapped_in_bytes": 0,
                       "swap_space_mib": round(
                           num_host_blocks * self._dev_block_bytes
-                          / 2 ** 20, 3)}
+                          / 2 ** 20, 3),
+                      "queue_wait_s": 0.0, "first_admits": 0}
         self.step_count = 0           # virtual clock: one step() = one tick
         self.latency_record_cap = latency_record_cap
         # retirement-time latency aggregation: bounded state the metrics
@@ -343,12 +353,17 @@ class InferenceEngine:
         self.hist = {"ttft_seconds": Histogram(SECONDS_BUCKETS),
                      "e2e_seconds": Histogram(SECONDS_BUCKETS),
                      "ttft_steps": Histogram(STEP_BUCKETS),
-                     "e2e_steps": Histogram(STEP_BUCKETS)}
+                     "e2e_steps": Histogram(STEP_BUCKETS),
+                     "queue_wait_seconds": self.sched.queue_wait}
         # streaming hooks for the async front-end (serving/frontend/):
         # on_token(req, tok) after every appended token, on_finish(req)
         # after the request has retired and released its cache resources
         self.on_token = None
         self.on_finish = None
+        # on_step(StepRecord) after every step that scheduled tokens; no
+        # record is built while it is None
+        self.on_step = None
+        self._step_calls = 0          # every step() call, for serve.step
 
     def _place_params(self, params, cfg: ModelConfig):
         """Place one model's weights on the mesh.
@@ -558,10 +573,11 @@ class InferenceEngine:
         """The jitted step with the full sampling pipeline, compiled on
         first use only (see ``_full_steps``)."""
         if has_chunk not in self._full_steps:
-            self._full_steps[has_chunk] = jax.jit(
-                functools.partial(self.runner.step, has_chunk=has_chunk,
-                                  full_sampling=True),
-                donate_argnums=(1,))
+            name = ("serve_step_chunk_full" if has_chunk
+                    else "serve_step_decode_full")
+            self._full_steps[has_chunk] = _jit_step(
+                name, self.runner.step, has_chunk=has_chunk,
+                full_sampling=True)
         return self._full_steps[has_chunk]
 
     def _build_arrays(self, plan: StepPlan, full: bool = False) -> dict:
@@ -663,7 +679,8 @@ class InferenceEngine:
             pos, _, _, _ = pack_ragged(pos_rows, C, S)
             a["c_tok"][0], a["c_pos"][0] = tok, pos
             a["c_seq"], a["c_starts"], a["c_ends"] = seq, starts, ends
-        return {k: jnp.asarray(v) for k, v in a.items()}
+        with TraceAnnotation("serve.h2d"):
+            return {k: jnp.asarray(v) for k, v in a.items()}
 
     def _lat(self, rid: int) -> dict:
         return self.stats["latency"].setdefault(rid, {})
@@ -776,9 +793,26 @@ class InferenceEngine:
             self.stats["encodes"] += 1
 
     def step(self) -> bool:
-        """One engine iteration. Returns True when any work ran."""
-        with jax.set_mesh(self.mesh):
-            plan = self.sched.schedule()
+        """One engine iteration. Returns True when any work ran.
+
+        Each phase is a profiler span (``jax.profiler.TraceAnnotation``,
+        about a microsecond when no trace is being captured), so a trace
+        taken with ``jax.profiler.start_trace`` puts the host's work on the
+        same clock as the device's operations: ``serve.step`` (the whole
+        call; stats ``step``, ``rows``, ``chunk_tokens``, ``full``) holds
+        ``serve.schedule`` (with ``serve.admit`` per admission),
+        ``serve.copies`` (swaps, encodes, copy-on-write), ``serve.build``
+        (host arrays, ``serve.h2d`` their transfer), ``serve.dispatch``,
+        ``serve.sync`` (waiting for the outputs) and ``serve.emit`` (token
+        appends, hooks, prefix publishing, retirement)."""
+        k = self._step_calls
+        self._step_calls += 1
+        on_step = self.on_step
+        t0 = time.perf_counter()
+        with jax.set_mesh(self.mesh), \
+                TraceAnnotation("serve.step", step=k) as span:
+            with TraceAnnotation("serve.schedule"):
+                plan = self.sched.schedule()
             self.stats["preemptions"] = self.sched.n_preemptions
             self.stats["swap_preemptions"] = self.sched.n_swap_preemptions
             self.stats["swap_ins"] = self.sched.n_swap_ins
@@ -787,6 +821,8 @@ class InferenceEngine:
             self.stats["cache_hit_tokens"] = self.sched.cache_hit_tokens
             self.stats["quantum_dropped_tokens"] = \
                 self.sched.quantum_dropped_tokens
+            self.stats["queue_wait_s"] = self.sched.queue_wait.total
+            self.stats["first_admits"] = self.sched.queue_wait.count
             if self.bm is not None:
                 st = self.bm.stats()
                 self.stats["peak_block_utilization"] = max(
@@ -802,25 +838,27 @@ class InferenceEngine:
             # overlapping the host copy with device compute. Swap-ins must
             # land before COW copies: a host-copied block registered this
             # step can already be a COW source for a later admission.
-            d2h_token = None
-            if plan.swap_outs:
-                d2h_token = self._issue_swap_out(plan.swap_outs)
-            if plan.swap_ins:
-                if d2h_token is not None:
-                    # same-step slot reuse: host content must exist first
-                    self._drain_swap_out(d2h_token)
-                    d2h_token = None
-                self._swap_in(plan.swap_ins)
-            if plan.shared_ins:
-                # cross-replica adoptions land with the swap-ins, before
-                # COW copies (an adopted block can be a COW source)
-                self._shared_in(plan.shared_ins)
-            self._run_encodes(plan)
-            for src, dst in plan.copies:
-                self.stats["cow_copies"] += 1
-                self.cache = self._copy_block(
-                    self.cache, jnp.asarray(src, jnp.int32),
-                    jnp.asarray(dst, jnp.int32))
+            with TraceAnnotation("serve.copies", cow=len(plan.copies)):
+                d2h_token = None
+                if plan.swap_outs:
+                    d2h_token = self._issue_swap_out(plan.swap_outs)
+                if plan.swap_ins:
+                    if d2h_token is not None:
+                        # same-step slot reuse: host content must exist
+                        self._drain_swap_out(d2h_token)
+                        d2h_token = None
+                    self._swap_in(plan.swap_ins)
+                if plan.shared_ins:
+                    # cross-replica adoptions land with the swap-ins,
+                    # before COW copies (an adopted block can be a COW
+                    # source)
+                    self._shared_in(plan.shared_ins)
+                self._run_encodes(plan)
+                for src, dst in plan.copies:
+                    self.stats["cow_copies"] += 1
+                    self.cache = self._copy_block(
+                        self.cache, jnp.asarray(src, jnp.int32),
+                        jnp.asarray(dst, jnp.int32))
             if plan.scheduled_tokens == 0:
                 # no compute, but an admission (e.g. a full prefix-cache
                 # hit that is immediately decode-ready) is still progress
@@ -838,7 +876,19 @@ class InferenceEngine:
                         for _, r in plan.decodes)
                     or any(r.sampling.needs_pipeline
                            for _, r, _ in plan.chunks))
-            arrays = self._build_arrays(plan, full)
+            span.set_metadata(rows=len(plan.decodes),
+                              chunk_tokens=sum(n for *_, n in plan.chunks),
+                              full=full)
+            if on_step is not None:
+                # the plan as it runs: contexts and chunk starts move below
+                planned = dict(
+                    decode_ctxs=tuple(r.context_len for _, r in plan.decodes),
+                    chunks=tuple((r.num_computed, n)
+                                 for _, r, n in plan.chunks),
+                    sampled=sum(r.num_computed + n == r.context_len
+                                for _, r, n in plan.chunks))
+            with TraceAnnotation("serve.build", full=full):
+                arrays = self._build_arrays(plan, full)
             if full:
                 self.stats["full_sampling_steps"] += 1
                 step_exec = self._full_step(plan.chunk is not None)
@@ -846,79 +896,87 @@ class InferenceEngine:
                 step_exec = (self._step_chunk if plan.chunk is not None
                              else self._step_plain)
             t_step = time.monotonic()
-            nxt, self.cache = step_exec(self.params, self.cache, arrays)
-            if d2h_token is not None:
-                self._drain_swap_out(d2h_token)
-            chunk_lp = None
-            if self.runner.spec_tokens or self.draft_cfg is not None:
-                if full:
-                    toks, n_acc, c_tok, lp_d, chunk_lp = nxt
-                    lp_d = {k: np.asarray(v) for k, v in lp_d.items()}
-                    chunk_lp = {k: np.asarray(v)
-                                for k, v in chunk_lp.items()}
-                else:
-                    toks, n_acc, c_tok = nxt
-                    lp_d = None
-                toks, n_acc = np.asarray(toks), np.asarray(n_acc)
-                chunk_toks = np.asarray(c_tok)
-                for slot, req in plan.decodes:
-                    self.stats["spec_decodes"] += 1
-                    # accepted draft prefix + the corrected / bonus token,
-                    # cut short by EOS or max_new retirement
-                    for i in range(int(n_acc[slot]) + 1):
-                        req.num_computed += 1
-                        self.stats["spec_emitted"] += 1
-                        self._append_token(
-                            slot, req, int(toks[slot, i]),
-                            self._req_logprobs(req, lp_d, (slot, i)))
-                        if req.done:
-                            break
-                    if self.sched.running.get(slot) is req:
-                        # roll back lookahead blocks the rejected draft
-                        # tail reserved (in both models' pools at once —
-                        # they share the block table)
-                        self.bm.truncate(req.rid, req.context_len)
-            else:
-                if full:
-                    toks, lp = nxt
-                    nxt = np.asarray(toks)
-                    lp = {k: np.asarray(v) for k, v in lp.items()}
-                    chunk_lp = {k: v[self.max_batch:]
-                                for k, v in lp.items()}
-                else:
-                    nxt = np.asarray(nxt)
-                    lp = None
-                chunk_toks = nxt[self.max_batch:]
-                for slot, req in plan.decodes:
-                    req.num_computed += 1
-                    self._append_token(slot, req, int(nxt[slot]),
-                                       self._req_logprobs(req, lp, slot))
-            for ci, (slot, req, n) in enumerate(plan.chunks):
-                req.num_computed += n
-                self.stats["prefill_chunks"] += 1
-                self.stats["prefill_tokens"] += n
-                if req.num_computed == req.context_len:
-                    self._append_token(
-                        slot, req, int(chunk_toks[ci]),
-                        self._req_logprobs(req, chunk_lp, ci))
-                else:
-                    self.sched.note_progress(req)
-            if self._swap_cost is not None and plan.chunks:
-                # np.asarray above already synced the step's outputs, so
-                # this wall time covers real device work: feed the
-                # recompute-throughput EMA the cost model weighs against
-                # moving bytes
-                self._swap_cost.observe_prefill(
-                    sum(c[2] for c in plan.chunks),
-                    time.monotonic() - t_step)
-            self._flush_shared_publish()
+            with TraceAnnotation("serve.dispatch"):
+                nxt, self.cache = step_exec(self.params, self.cache, arrays)
+            with TraceAnnotation("serve.sync"):
+                if d2h_token is not None:
+                    self._drain_swap_out(d2h_token)
+                nxt = jax.tree.map(np.asarray, nxt)
+            with TraceAnnotation("serve.emit"):
+                self._emit(plan, nxt, full, t_step)
             self.stats["steps"] += 1
             self.step_count += 1
             if self.debug_invariants and self.bm is not None:
                 self.bm.check()
                 if self.shared_index is not None:
                     self.shared_index.check()
+            if on_step is not None:
+                s = self.stats
+                on_step(StepRecord(
+                    step=k, t0=t0, t1=time.perf_counter(), **planned,
+                    full=full, waiting=len(self.sched.waiting),
+                    cache_hit_tokens=s["cache_hit_tokens"],
+                    prefill_tokens=s["prefill_tokens"],
+                    queue_wait_s=s["queue_wait_s"],
+                    first_admits=s["first_admits"]))
             return True
+
+    def _emit(self, plan: StepPlan, nxt, full: bool, t_step: float) -> None:
+        """Hand the step's synced outputs (numpy) to their requests:
+        append tokens (``on_token``), advance chunks, retire finished
+        requests, and publish newly full prefix blocks."""
+        chunk_lp = None
+        if self.runner.spec_tokens or self.draft_cfg is not None:
+            if full:
+                toks, n_acc, chunk_toks, lp_d, chunk_lp = nxt
+            else:
+                (toks, n_acc, chunk_toks), lp_d = nxt, None
+            for slot, req in plan.decodes:
+                self.stats["spec_decodes"] += 1
+                # accepted draft prefix + the corrected / bonus token,
+                # cut short by EOS or max_new retirement
+                for i in range(int(n_acc[slot]) + 1):
+                    req.num_computed += 1
+                    self.stats["spec_emitted"] += 1
+                    self._append_token(
+                        slot, req, int(toks[slot, i]),
+                        self._req_logprobs(req, lp_d, (slot, i)))
+                    if req.done:
+                        break
+                if self.sched.running.get(slot) is req:
+                    # roll back lookahead blocks the rejected draft
+                    # tail reserved (in both models' pools at once —
+                    # they share the block table)
+                    self.bm.truncate(req.rid, req.context_len)
+        else:
+            if full:
+                toks, lp = nxt
+                chunk_lp = {k: v[self.max_batch:] for k, v in lp.items()}
+            else:
+                toks, lp = nxt, None
+            chunk_toks = toks[self.max_batch:]
+            for slot, req in plan.decodes:
+                req.num_computed += 1
+                self._append_token(slot, req, int(toks[slot]),
+                                   self._req_logprobs(req, lp, slot))
+        for ci, (slot, req, n) in enumerate(plan.chunks):
+            req.num_computed += n
+            self.stats["prefill_chunks"] += 1
+            self.stats["prefill_tokens"] += n
+            if req.num_computed == req.context_len:
+                self._append_token(
+                    slot, req, int(chunk_toks[ci]),
+                    self._req_logprobs(req, chunk_lp, ci))
+            else:
+                self.sched.note_progress(req)
+        if self._swap_cost is not None and plan.chunks:
+            # the step's outputs were synced before this, so this wall
+            # time covers real device work: feed the recompute-throughput
+            # EMA the cost model weighs against moving bytes
+            self._swap_cost.observe_prefill(
+                sum(c[2] for c in plan.chunks),
+                time.monotonic() - t_step)
+        self._flush_shared_publish()
 
     def _check_invariants(self, plan: StepPlan) -> None:
         for cache in (self.slot_cache, self.encoder_cache):

@@ -39,6 +39,8 @@ import time
 from collections import deque
 from typing import NamedTuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.serving.frontend.admission import AdmissionController
 
 __all__ = ["AsyncEngineDriver", "TokenStream", "TokenEvent", "ShedError"]
@@ -67,6 +69,9 @@ class TokenEvent(NamedTuple):
     # per-token logprobs ({"token_logprob": float, "top": [(id, lp), ...]})
     # when the request asked for them (SamplingParams.logprobs > 0)
     logprobs: dict | None = None
+    # time.perf_counter() when the engine thread handed the token over;
+    # the consumer's own clock reading minus this is the handoff time
+    emitted: float = float("nan")
 
 
 _DONE = object()
@@ -99,7 +104,7 @@ class TokenStream:
         if self.first_token_wall is None:
             self.first_token_wall = time.monotonic()
         self._loop.call_soon_threadsafe(
-            self._q.put_nowait, (int(tok), logprobs))
+            self._q.put_nowait, (int(tok), logprobs, time.perf_counter()))
 
     def _finish(self) -> None:
         self._loop.call_soon_threadsafe(self._q.put_nowait, _DONE)
@@ -122,8 +127,8 @@ class TokenStream:
             if self.error is not None:
                 raise self.error
             raise StopAsyncIteration
-        tok, logprobs = item
-        ev = TokenEvent(self._n, tok, self._detok(tok), logprobs)
+        tok, logprobs, emitted = item
+        ev = TokenEvent(self._n, tok, self._detok(tok), logprobs, emitted)
         self._n += 1
         return ev
 
@@ -332,38 +337,46 @@ class AsyncEngineDriver:
 
     # -- the step loop (background thread) -----------------------------------
 
+    def _take_inbox(self, pending: list) -> None:
+        """Between steps: pull submissions and aborts off the inbox, apply
+        the aborts, and hand every arrival that is due to the scheduler."""
+        eng = self.engine
+        # block only when there is nothing else to do and we are not
+        # waiting on a scheduled arrival
+        block = not eng.sched.has_work and not pending \
+            and not self._draining
+        try:
+            while True:
+                item = self._inbox.get(block=block,
+                                       timeout=self._idle_wait_s)
+                block = False
+                if item is None:              # None = wake-up ping
+                    continue
+                if item[0] == "abort":
+                    self._abort_q.append(item[1])
+                    continue
+                heapq.heappush(pending, item)
+        except queue.Empty:
+            pass
+        # cancellations apply between steps, before this tick's
+        # admissions, so an aborted request never re-enters a plan
+        while self._abort_q:
+            self._apply_abort(pending, self._abort_q.popleft())
+        # admit every arrival due on the virtual clock, in submission
+        # order — the same order engine.run() uses
+        while pending and pending[0][0] <= eng.step_count:
+            _, _, req = heapq.heappop(pending)
+            eng.sched.add(req)
+            eng._note_arrival(req)
+
     def _run(self) -> None:
         eng = self.engine
         pending: list[tuple[int, int, object]] = []   # (step, seq, req)
         try:
             while True:
-                # pull submissions; block only when there is nothing else
-                # to do and we are not waiting on a scheduled arrival
-                block = not eng.sched.has_work and not pending \
-                    and not self._draining
-                try:
-                    while True:
-                        item = self._inbox.get(
-                            block=block, timeout=self._idle_wait_s)
-                        block = False
-                        if item is None:              # None = wake-up ping
-                            continue
-                        if item[0] == "abort":
-                            self._abort_q.append(item[1])
-                            continue
-                        heapq.heappush(pending, item)
-                except queue.Empty:
-                    pass
-                # cancellations apply between steps, before this tick's
-                # admissions, so an aborted request never re-enters a plan
-                while self._abort_q:
-                    self._apply_abort(pending, self._abort_q.popleft())
-                # admit every arrival due on the virtual clock, in
-                # submission order — the same order engine.run() uses
-                while pending and pending[0][0] <= eng.step_count:
-                    _, _, req = heapq.heappop(pending)
-                    eng.sched.add(req)
-                    eng._note_arrival(req)
+                # the loop's own work between steps is a profiler span
+                with TraceAnnotation("serve.loop"):
+                    self._take_inbox(pending)
                 if eng.sched.has_work:
                     if not eng.step():
                         raise RuntimeError(

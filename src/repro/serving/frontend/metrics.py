@@ -3,7 +3,8 @@
 One function, :func:`render_metrics`, snapshots the engine's counters,
 the derived rates (cache-hit rate, preemption rate, mean accept length —
 the *same accessors* the bench and serve.py print, so every surface
-reports identical numbers), the retirement-time TTFT/e2e histograms, and
+reports identical numbers), the retirement-time TTFT/e2e histograms and
+the scheduler's queue-wait histogram, and
 — when a driver is attached — the front-end queue/shed/drain state. The
 output is the Prometheus text exposition format v0.0.4 (`# HELP` /
 `# TYPE` comments, cumulative `_bucket{le=...}` histogram lines), which
@@ -88,6 +89,9 @@ _HISTOGRAMS = (
      "Time to first token in engine steps (deterministic virtual clock)"),
     ("e2e_steps", "repro_engine_e2e_steps",
      "End-to-end request latency in engine steps"),
+    ("queue_wait_seconds", "repro_engine_queue_wait_seconds",
+     "Seconds from reaching the scheduler to a request's first batch "
+     "slot (preemption re-admissions not counted)"),
 )
 
 

@@ -375,8 +375,7 @@ class ReplicaRouter:
                 # decode-ready (no prefill recompute on the decode side)
                 inner2 = await self.drivers[j].submit(cont)
                 async for ev in inner2:
-                    stream._put(TokenEvent(ev.index + 1, ev.token,
-                                           ev.text, ev.logprobs))
+                    stream._put(ev._replace(index=ev.index + 1))
             finally:
                 self._outstanding[j] -= cost2
             stream._close()

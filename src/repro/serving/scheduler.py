@@ -55,18 +55,30 @@ Invariants this module maintains (asserted by ``validate``, the engine's
   the same plans on any mesh shape — pinned by the TP walks and the
   subprocess stats-equality tests in tests/test_serving_tp.py.
 
-Pure host-side and jax-free so the policy is unit-testable in isolation.
+Queue wait: the seconds from ``add`` to a request's first slot binding
+(``time.perf_counter``) feed ``queue_wait``, a histogram whose ``total`` and
+``count`` are the cumulative wait and the number of requests that have
+reached a slot; a preempted request's re-admission does not count again,
+nor does a request that arrives already holding output tokens (the decode
+continuation of a disaggregated request, whose wait its prefill replica
+counted).
+
+Pure host-side so the policy is unit-testable in isolation; each admission
+is a ``serve.admit`` profiler span.
 """
 
 from __future__ import annotations
 
 import itertools
+import time
 from collections import deque
 from dataclasses import dataclass, field
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.kv_cache import BlockManager, extend_chain_hashes
+from repro.serving.stats import SECONDS_BUCKETS, Histogram
 
 _RID = itertools.count()
 
@@ -336,6 +348,10 @@ class Scheduler:
         # front-end hook: called as on_admit(slot, req) whenever a request
         # moves waiting -> running (including preemption re-admissions)
         self.on_admit = None
+        # add() time of each request not yet bound to a slot, and the
+        # waits of those that were (their first binding only)
+        self._queued_at: dict[int, float] = {}
+        self.queue_wait = Histogram(SECONDS_BUCKETS)
 
     # -- queries ----------------------------------------------------------
 
@@ -375,6 +391,8 @@ class Scheduler:
                 f"scheduler is draining: request {req.rid} refused "
                 "(in-flight work finishes; no new admissions)")
         self.validate(req)
+        if not req.out:
+            self._queued_at[req.rid] = time.perf_counter()
         self.waiting.append(req)
 
     def drain(self) -> None:
@@ -521,6 +539,12 @@ class Scheduler:
         caches are bound to the chosen slot; enc-dec requests are queued
         for their admission-time encode pass."""
         req = self.waiting.popleft()
+        with TraceAnnotation("serve.admit", prompt_tokens=req.context_len):
+            return self._admit(req, copies, encodes)
+
+    def _admit(self, req: Request, copies: list[tuple[int, int]],
+               encodes: list[tuple[int, Request]] | None) -> \
+            tuple[int, Request]:
         if self.bm is None:
             return self._bind_slot(req, encodes)
         if self.bm.is_swapped(req.rid):
@@ -626,6 +650,9 @@ class Scheduler:
                    encodes: list[tuple[int, Request]] | None) -> \
             tuple[int, Request]:
         slot = self.free_slots()[0]
+        t_add = self._queued_at.pop(req.rid, None)
+        if t_add is not None:
+            self.queue_wait.observe(time.perf_counter() - t_add)
         self.running[slot] = req
         self._join_order.append(slot)
         if self.sampling_buffer is not None:
@@ -714,6 +741,7 @@ class Scheduler:
         for i, r in enumerate(self.waiting):
             if r.rid == rid:
                 del self.waiting[i]
+                self._queued_at.pop(rid, None)
                 if self.bm is not None and self.bm.is_swapped(rid):
                     self.bm.swap_discard(rid)
                 self.n_aborts += 1

@@ -1,4 +1,5 @@
-"""Serving statistics primitives: Prometheus-style histograms.
+"""Serving statistics primitives: Prometheus-style histograms and the
+per-step record the engine hands its ``on_step`` hook.
 
 The engine aggregates per-request TTFT / end-to-end latency into fixed-
 bucket :class:`Histogram`\\ s at retirement time, so the rolling
@@ -15,8 +16,9 @@ path and the asyncio front-end both import it.
 from __future__ import annotations
 
 from bisect import bisect_left
+from dataclasses import dataclass
 
-__all__ = ["Histogram", "SECONDS_BUCKETS", "STEP_BUCKETS"]
+__all__ = ["Histogram", "SECONDS_BUCKETS", "STEP_BUCKETS", "StepRecord"]
 
 # wall-clock latency buckets (seconds): spans interpret-mode CPU smoke
 # runs (tens of seconds) down to real-accelerator decode steps (ms)
@@ -109,3 +111,35 @@ class Histogram:
         out.append(f'{name}_bucket{{{base}le="+Inf"}} {self.count}')
         out.append(f"{name}_sum{tail} {format(self.total, 'g')}")
         out.append(f"{name}_count{tail} {self.count}")
+
+
+@dataclass(frozen=True)
+class StepRecord:
+    """One ``InferenceEngine.step`` that scheduled tokens, as its
+    ``on_step`` hook receives it.
+
+    ``step`` is the index the ``serve.step`` profiler span carries, so a
+    record can be matched with its span (and the device operations inside
+    it) in a captured trace. ``t0``/``t1`` are ``time.perf_counter()`` at
+    the call's entry and just before the hook runs. The plan fields are
+    read before the step ran: each decode row's context length (the token
+    fed included), each prefill chunk's ``(start, n)``, and how many chunks
+    end their prompt and so sample a token. The counters are cumulative
+    engine totals after the step."""
+    step: int
+    t0: float
+    t1: float
+    decode_ctxs: tuple[int, ...]
+    chunks: tuple[tuple[int, int], ...]
+    sampled: int
+    full: bool                 # ran the full sampling pipeline
+    waiting: int               # requests in the scheduler's queue after
+    cache_hit_tokens: int
+    prefill_tokens: int
+    queue_wait_s: float        # Σ add → first slot binding, seconds
+    first_admits: int          # requests that reached a slot at least once
+
+    @property
+    def chunk(self) -> tuple[int, int] | None:
+        """The first (with ``prefill_pack=1`` the only) chunk, or None."""
+        return self.chunks[0] if self.chunks else None
