@@ -598,12 +598,12 @@ def bench_serving_dp(rows):
 
 
 # ---------------------------------------------------------------------------
-# Paged-attention kernel rows: decode and chunked prefill through the
-# dispatch layer with the pages_per_compute_block knob, plus the ragged
-# packed-prefill op (fused KV scatter + attention). On CPU these time the
-# XLA dispatch path (the knob is a no-op there); on TPU the same calls hit
-# the Pallas kernels with multi-page fetch and megacore grid partitioning,
-# so the rows track the kernel campaign wherever the bench runs.
+# Paged-attention kernel rows: decode (pages per block from the shapes)
+# and chunked prefill (pages_per_compute_block knob) through the dispatch
+# layer, plus the ragged packed-prefill op (fused KV scatter + attention).
+# On CPU these time the XLA dispatch path (the knob is a no-op there); on
+# TPU the same calls hit the Pallas kernels, so the rows track the kernels
+# wherever the bench runs.
 # ---------------------------------------------------------------------------
 
 
@@ -635,14 +635,10 @@ def bench_paged_kernels(rows):
         return (time.perf_counter() - t0) / n
 
     q_d = jnp.asarray(rng.normal(0, 1, (B, H, hd)), jnp.bfloat16)
-    for p, name in ((1, "kernels/paged_decode"),
-                    (4, "kernels/paged_decode_mp")):
-        dt = timeit(lambda q, pp=p: kops.paged_attention(
-            q, k_pages, v_pages, tables, ctx,
-            pages_per_compute_block=pp), q_d)
-        rows.append(_csv(name, dt * 1e6,
-                         f"tok_s={B/dt:.0f} pages_per_block={p} "
-                         f"backend={backend}"))
+    dt = timeit(lambda q: kops.paged_attention(
+        q, k_pages, v_pages, tables, ctx), q_d)
+    rows.append(_csv("kernels/paged_decode", dt * 1e6,
+                     f"tok_s={B/dt:.0f} backend={backend}"))
 
     C = 32
     q_p = jnp.asarray(rng.normal(0, 1, (B, C, H, hd)), jnp.bfloat16)

@@ -37,9 +37,10 @@ def _use_pallas() -> str | None:
 
 
 def _pages_per_block(pages_per_compute_block) -> int:
-    """KV pages fetched per paged-kernel grid step. Explicit argument wins;
-    ``REPRO_PAGES_PER_BLOCK`` sets the fleet-wide default (1 = the
-    single-page kernel, bit-for-bit)."""
+    """KV pages fetched per grid step of the chunk and ragged kernels.
+    Explicit argument wins; ``REPRO_PAGES_PER_BLOCK`` sets the fleet-wide
+    default (1 = the single-page kernel, bit-for-bit). The decode kernel
+    takes its pages per block from the shapes and reads neither."""
     if pages_per_compute_block is not None:
         return int(pages_per_compute_block)
     return int(os.environ.get("REPRO_PAGES_PER_BLOCK", "1"))
@@ -74,8 +75,8 @@ def flash_attention(q, k, v, *, causal=True, window=None, cap=None,
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
-                    window=None, cap=None, scale=None,
-                    pages_per_compute_block=None, k_scale=None, v_scale=None):
+                    window=None, cap=None, scale=None, k_scale=None,
+                    v_scale=None):
     """Decode attention through a block table (serving hot path).
     See kernels/paged_attention.py; the XLA path densifies the gather.
     ``k_scale``/``v_scale`` are the per-row fp32 scale pools of a
@@ -86,8 +87,6 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         return pa.paged_attention(
             q, k_pages, v_pages, block_tables, ctx_lens, window=window,
             cap=cap, scale=scale, interpret=(mode == "interpret"),
-            pages_per_compute_block=_pages_per_block(
-                pages_per_compute_block),
             k_scale=k_scale, v_scale=v_scale)
     from repro.kernels.ref import paged_attention_ref
     return paged_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens,

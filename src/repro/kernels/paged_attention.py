@@ -2,41 +2,44 @@
 
 One query token per sequence attends to a KV cache that lives in fixed-size
 *blocks* scattered through two page pools shaped
-``(num_blocks, K, block_size, hd)`` — head-major, so one page of one kv
-head is a whole ``(block_size, hd)`` tile: Mosaic requires a block's last
-two dims to be tile-aligned or whole, and each grid step DMAs exactly one
-live page of one kv head. A per-sequence *block table* names the
-pool rows holding that sequence's KV, in order; the serving block manager
+``(num_blocks, K, block_size, hd)`` — head-major, so a pool row is one
+contiguous page of every kv head and one kv head's page is a whole
+``(block_size, hd)`` tile (Mosaic requires a block's last two dims to be
+tile-aligned or whole). A per-sequence *block table* names the pool rows
+holding that sequence's KV, in order; the serving block manager
 (``repro.serving.kv_cache``) owns the tables and the free list.
 
-Layout: grid = (B * K, max_blocks_per_seq) — one program per (sequence,
-kv-head) pair, with the kv-block index as the minormost (sequential) dim so
-an (m, l, acc) streaming-softmax state survives across blocks in VMEM
-scratch, exactly like ``flash_attention.py``. The block table and the
-context lengths are *scalar-prefetched* so the BlockSpec index maps can
-gather the right pool row per grid step — the pages are never densified.
-The B*K axis is megacore-partitioned (``dimension_semantics`` marks it
-"parallel"); the block axis stays "arbitrary" because the scratch
-accumulator is carried across it.
-
-``pages_per_compute_block`` batches several KV pages into one grid step:
-the kernel takes P separate (k, v) page operands — pool rows named by a
-block table are not contiguous, so each page needs its own BlockSpec index
-map — concatenates them into a (P*block_size, hd) tile and runs one matmul
-over it, cutting grid steps (and per-step DMA turnarounds) by P. P == 1
-reproduces the single-page kernel bit-for-bit.
+The decode kernel: grid = (B,), one program per sequence holding all K kv
+heads. The pools stay in HBM (``memory_space=pl.ANY``); the block table,
+context lengths and block mask are scalar-prefetched. A ``fori_loop`` runs
+over the row's *live* compute blocks only — from the first block inside
+the sliding window to ``cdiv(ctx, P * block_size)`` — and each block
+copies just the table entries that hold keys the row attends, one DMA per
+pool row, double-buffered so block j+1's copies overlap block j's
+attention. Entries at or past ``cdiv(ctx, block_size)`` are never read; an
+inactive slot (ctx_len == 0) is one program that copies nothing and writes
+zeros. The pages per compute block P come from the shapes
+(``decode_pages_per_block``: as many as two (k, v) buffers hold in 512 KiB
+of VMEM, 16 at K 2, hd 128, block 16 in bf16); no option or environment
+variable sets them. An fp32 streaming softmax (m, l, acc) per kv head runs
+over the P * block_size keys of each block.
 
 GQA uses the repo-wide g-major convention: q head h reads kv head h % K,
 so q is regrouped to (B*K, G, hd) and each program computes all G query
-heads of its kv head. Blocks wholly past the context length are skipped via
-``pl.when``; a sequence with ctx_len == 0 (inactive serving slot) produces
-zeros. ``interpret=True`` runs the same kernel on CPU for tests.
+heads of each of its kv heads. ``interpret=True`` runs the same kernel on
+CPU for tests.
 
 ``paged_prefill_attention`` is the multi-query sibling for chunked prefill:
 C chunk queries per sequence, each causally masked at its absolute position
 against the same paged context (C == 1 reproduces the decode kernel
-exactly). The serving engine uses it to stream long prompts in while other
-sequences keep decoding.
+exactly at the same pages per block). The serving engine uses it to stream
+long prompts in while other sequences keep decoding. Its grid is
+(B * K, cdiv(max_blocks_per_seq, P)), one program per (sequence, kv head,
+P table entries), live or not: scalar-prefetched block tables feed the
+BlockSpec index maps, dead steps are skipped with ``pl.when``, and
+``pages_per_compute_block`` (``REPRO_PAGES_PER_BLOCK`` through
+``kernels.ops``) batches P pages per grid step through P separate page
+operands.
 
 ``ragged_paged_prefill_attention`` packs chunks of *several* sequences into
 one flat (T, H, hd) batch (per-sequence [start, end) row offsets, scalar-
@@ -79,105 +82,175 @@ def _dequant_tile(x, s):
         .astype(jnp.float32)
 
 
-def _live_columns(lives, shape, block_size):
-    """(rows, P * block_size) mask of the score columns whose page is live.
-    Built from an iota: Mosaic cannot lower a concatenate of booleans."""
-    page = jax.lax.broadcasted_iota(jnp.int32, shape, 1) // block_size
+def _live_columns(lives, shape, block_size, axis=1):
+    """Mask over ``shape`` of the key positions (along ``axis``, P *
+    block_size of them) whose page is live. Built from an iota: Mosaic
+    cannot lower a concatenate of booleans."""
+    page = jax.lax.broadcasted_iota(jnp.int32, shape, axis) // block_size
     return functools.reduce(lambda a, c: a | c,
                             [(page == i) & li for i, li in enumerate(lives)])
 
 
-def _decode_kernel(bt_ref, ctx_ref, mask_ref, q_ref, *rest, scale, cap,
-                   window, block_size, num_kv_heads, pages_per_block,
-                   table_width, with_lse, with_scales):
-    P = pages_per_block
-    k_refs, v_refs = rest[:P], rest[P:2 * P]
-    rest = rest[2 * P:]
-    ks_refs = vs_refs = None
+# VMEM for one decode row's two (k, v) page buffers: the pages fetched per
+# compute block are as many as fit (16 pages, 256 keys, at K 2, hd 128,
+# block 16 in bf16)
+_DECODE_VMEM_BYTES = 512 * 1024
+
+
+def _scale_lanes(num_kv_heads, block_size):
+    """Lanes of one page's scale row: its K * block_size fp32 scales,
+    padded to whole 128-lane tiles so a DMA can fetch the row."""
+    return -(-num_kv_heads * block_size // 128) * 128
+
+
+def decode_pages_per_block(num_kv_heads, block_size, head_dim, kv_dtype,
+                           with_scales, table_width):
+    """Pages per compute block of the decode kernel, from the pool's
+    shapes: as many whole pages (every kv head of a pool row) as two
+    (k, v) buffers hold in ``_DECODE_VMEM_BYTES``, at least one and at
+    most the table width. A scale row takes an (8, lanes) fp32 tile."""
+    page = num_kv_heads * block_size * head_dim * \
+        jnp.dtype(kv_dtype).itemsize
     if with_scales:
-        ks_refs, vs_refs = rest[:P], rest[P:2 * P]
-        rest = rest[2 * P:]
-    o_ref = rest[0]
-    tail = rest[1:]
+        page += 8 * _scale_lanes(num_kv_heads, block_size) * 4
+    return max(1, min(table_width, _DECODE_VMEM_BYTES // (2 * 2 * page)))
+
+
+def _decode_kernel(bt_ref, ctx_ref, mask_ref, q_ref, k_hbm, v_hbm, *rest,
+                   scale, cap, window, block_size, pages_per_block,
+                   table_width, with_mask, with_lse, with_scales):
+    """One program per sequence; a loop over its live compute blocks.
+
+    The pools stay in HBM. Block j covers table entries [j*P, (j+1)*P);
+    only entries that hold keys this row attends (below ``ctx``, inside
+    the window, held per ``block_mask``) are copied, each pool row —
+    every kv head of the page — in one DMA, into one of two VMEM slots:
+    block j+1's copies run while block j is attended. The loop runs from
+    the first block in the window to ``cdiv(ctx, P * block_size)``, so an
+    inactive slot (ctx 0) copies nothing. Stale slot contents (entries
+    not copied this block) are masked out of both matmuls.
+
+    Quantized pools come with one (1, lanes) scale row per page
+    (``_scale_lanes``); a transpose puts each head's scales in a column
+    for the dequant of its (block_size, hd) rows.
+    """
+    P, bs = pages_per_block, block_size
+    if with_scales:
+        ks_hbm, vs_hbm, *rest = rest
+    o_ref, *rest = rest
     if with_lse:
-        lse_ref, m_scr, l_scr, acc_scr = tail
-    else:
-        m_scr, l_scr, acc_scr = tail
-    bk = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    b = bk // num_kv_heads
+        lse_ref, *rest = rest
+    k_buf, v_buf, *rest = rest
+    if with_scales:
+        ks_buf, vs_buf, *rest = rest
+    sem, m_scr, l_scr, acc_scr = rest
+    K = q_ref.shape[0]
+    b = pl.program_id(0)
     ctx = ctx_ref[b]
+    n_live = (ctx + bs - 1) // bs               # pages holding a key < ctx
+    first = 0 if window is None else jnp.maximum(ctx - window, 0) // bs
+    lo, hi = first // P, (n_live + P - 1) // P
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def live(e):
+        ok = (e >= first) & (e < n_live)
+        if with_mask:
+            ok &= mask_ref[b, jnp.minimum(e, table_width - 1)] != 0
+        return ok
 
-    first_k = j * (P * block_size)
-    # per-page liveness; the step runs if any of its P pages is live
-    lives = []
-    for i in range(P):
-        entry = j * P + i
-        seg_first = first_k + i * block_size
-        li = (seg_first < ctx) & \
-            (mask_ref[b, jnp.minimum(entry, table_width - 1)] != 0)
-        if P > 1:
-            li &= entry < table_width
-        if window is not None:
-            li &= seg_first + block_size - 1 > ctx - 1 - window
-        lives.append(li)
-    live = functools.reduce(lambda a, c: a | c, lives)
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[...].astype(jnp.float32)              # (G, hd)
+    def fetch(j, slot, op):
+        """Start (or wait for) the copies of block j's live pages."""
+        pairs = [(k_hbm, k_buf, 0), (v_hbm, v_buf, 1)]
         if with_scales:
-            k = jnp.concatenate(
-                [_dequant_tile(r[...], sr[...])
-                 for r, sr in zip(k_refs, ks_refs)], axis=0)
-        else:
-            k = jnp.concatenate(
-                [r[...] for r in k_refs], axis=0).astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (G, P*block_size)
-        if cap is not None:
-            s = cap * jnp.tanh(s / cap)
-        k_pos = first_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        mask = k_pos < ctx
-        if window is not None:
-            mask &= k_pos > ctx - 1 - window
-        if P > 1:
-            # columns of dead pages (past the table, masked out, or wholly
-            # past ctx) carry redirected/garbage KV — mask them out
-            mask &= _live_columns(lives, s.shape, block_size)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
-        m_scr[...] = m_new
-        if with_scales:
-            v = jnp.concatenate(
-                [_dequant_tile(r[...], sr[...])
-                 for r, sr in zip(v_refs, vs_refs)], axis=0)
-        else:
-            v = jnp.concatenate(
-                [r[...] for r in v_refs], axis=0).astype(jnp.float32)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            pairs += [(ks_hbm, ks_buf, 0), (vs_hbm, vs_buf, 1)]
+        for i in range(P):
+            e = j * P + i
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-37)
-        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
-        if with_lse:
-            lse_ref[...] = m_scr[...] + jnp.log(l)
+            @pl.when(live(e))
+            def _():
+                row = bt_ref[b, jnp.minimum(e, table_width - 1)]
+                for src, dst, kind in pairs:
+                    copy = pltpu.make_async_copy(
+                        src.at[row], dst.at[slot, i], sem.at[kind, slot])
+                    getattr(copy, op)()
+
+    def tile(buf, slot, h, scales_t=None):
+        """(P*bs, hd) fp32 keys or values of kv head h in a slot."""
+        x = buf[slot, :, h].astype(jnp.float32)          # (P, bs, hd)
+        if scales_t is not None:
+            col = jnp.stack([scales_t[h * bs:(h + 1) * bs, i:i + 1]
+                             for i in range(P)])         # (P, bs, 1)
+            x = _dequant_tile(x, col)
+        return x.reshape(P * bs, x.shape[-1])
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(lo < hi)
+    def _():
+        fetch(lo, 0, "start")
+
+    def block(j, carry):
+        slot = (j - lo) % 2
+
+        @pl.when(j + 1 < hi)
+        def _():
+            fetch(j + 1, 1 - slot, "start")
+
+        fetch(j, slot, "wait")
+        lives = [live(j * P + i) for i in range(P)]
+
+        def key_mask(shape, axis):
+            k_pos = j * (P * bs) + jax.lax.broadcasted_iota(
+                jnp.int32, shape, axis)
+            mask = k_pos < ctx
+            if window is not None:
+                mask &= k_pos > ctx - 1 - window
+            if with_mask:
+                mask &= _live_columns(lives, shape, bs, axis)
+            return mask
+
+        def attend():
+            G = q_ref.shape[1]
+            mask = key_mask((G, P * bs), 1)
+            v_rows = key_mask((P * bs, 1), 0)
+            ks_t = vs_t = None
+            if with_scales:                              # (lanes, P8)
+                ks_t = ks_buf[slot, :, 0, :].T
+                vs_t = vs_buf[slot, :, 0, :].T
+            for h in range(K):
+                q = q_ref[h].astype(jnp.float32)                 # (G, hd)
+                s = jax.lax.dot_general(
+                    q, tile(k_buf, slot, h, ks_t), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # (G, P*bs)
+                if cap is not None:
+                    s = cap * jnp.tanh(s / cap)
+                s = jnp.where(mask, s, NEG_INF)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                l_scr[h] = l_scr[h] * corr + p.sum(axis=1, keepdims=True)
+                m_scr[h] = m_new
+                # a slot row not copied this block may hold anything (NaN
+                # too): zero it, since p == 0 does not cancel a NaN
+                v = jnp.where(v_rows, tile(v_buf, slot, h, vs_t), 0.0)
+                acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+        if with_mask:
+            # a block whose every entry another shard holds attends nothing
+            pl.when(functools.reduce(lambda a, c: a | c, lives))(attend)
+        else:
+            attend()
+        return carry
+
+    jax.lax.fori_loop(lo, hi, block, 0)
+    l = jnp.maximum(l_scr[...], 1e-37)
+    o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+    if with_lse:
+        lse_ref[...] = m_scr[...] + jnp.log(l)
 
 
 def _head_major(o, B, K, G):
@@ -188,8 +261,9 @@ def _head_major(o, B, K, G):
     return o.transpose(*perm).reshape(B, G * K, *tail)
 
 
-def _page_specs(nb, P, K, block_size, hd, n_extra_scalars):
-    """P (k, v) BlockSpecs, each fetching table entry j*P + i.
+def _page_specs(nb, P, K, block_size, hd):
+    """P (k, v) BlockSpecs of the chunk kernel, each fetching table entry
+    j*P + i.
 
     Entries past the table width (last grid step when P does not divide
     nb) and block-masked entries redirect the fetch to pool row 0 so a
@@ -197,8 +271,7 @@ def _page_specs(nb, P, K, block_size, hd, n_extra_scalars):
     per-page liveness masks their columns.
     """
     def mk(i):
-        def page_index(bk, j, bt_ref, ctx_ref, *extra):
-            mask_ref = extra[n_extra_scalars]
+        def page_index(bk, j, bt_ref, ctx_ref, qlen_ref, mask_ref):
             b = bk // K
             entry = jnp.minimum(j * P + i, nb - 1)
             ok = (j * P + i < nb) & (mask_ref[b, entry] != 0)
@@ -212,19 +285,20 @@ def _page_specs(nb, P, K, block_size, hd, n_extra_scalars):
 def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
                     window=None, cap=None, scale=None, interpret=False,
                     block_mask=None, return_lse=False,
-                    pages_per_compute_block=1,
+                    pages_per_compute_block=None,
                     k_scale=None, v_scale=None):
     """q: (B, H, hd) one decode token per sequence.
     k_pages/v_pages: (num_blocks, K, block_size, hd).
-    block_tables: (B, max_blocks_per_seq) int32 pool-row ids (padding rows
-    are ignored past ctx). ctx_lens: (B,) int32 — tokens visible per
-    sequence, 0 for an inactive slot (output row is zeros).
-    Returns (B, H, hd) in q.dtype.
+    block_tables: (B, max_blocks_per_seq) int32 pool-row ids (entries at
+    or past cdiv(ctx, block_size) are never read). ctx_lens: (B,) int32 —
+    tokens visible per sequence, 0 for an inactive slot (output row is
+    zeros). Returns (B, H, hd) in q.dtype.
 
-    ``pages_per_compute_block`` fetches that many KV pages per grid step
-    (one matmul over the concatenated tile); 1 reproduces the single-page
-    kernel bit-for-bit, larger values cut the grid (and DMA turnarounds)
-    by the same factor at identical math up to fp reduction order.
+    The pages per compute block come from the shapes
+    (:func:`decode_pages_per_block`); ``pages_per_compute_block`` pins
+    them (clamped to the table width), which the tests use to run many
+    blocks per row at small shapes. The result is the same up to fp
+    reduction order.
 
     ``block_mask`` (B, max_blocks_per_seq) selects the table entries this
     shard holds pages for (None = all): masked entries are skipped, never
@@ -241,56 +315,77 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     ``quant.dequantize_kv`` bf16 round-trip) before the matmuls — the
     pool itself is never widened.
     """
-    B, H, hd = q.shape
+    B, H, hd_in = q.shape
     _, K, block_size, _ = k_pages.shape
     G = H // K
     nb = block_tables.shape[1]
-    P = max(1, min(int(pages_per_compute_block), nb))
-    scale = hd ** -0.5 if scale is None else scale
+    scale = hd_in ** -0.5 if scale is None else scale
+    # Mosaic copies only pool rows whose last dim fills whole 128-lane
+    # tiles: a narrower head (whisper's 64, zamba2's 80) is zero-padded,
+    # which leaves every score and the output's first hd_in lanes as they
+    # were
+    hd = -(-hd_in // 128) * 128
+    if hd != hd_in:
+        widen = [(0, 0)] * 3 + [(0, hd - hd_in)]
+        q = jnp.pad(q, widen[1:])
+        k_pages, v_pages = jnp.pad(k_pages, widen), jnp.pad(v_pages, widen)
     with_scales = k_scale is not None
+    if pages_per_compute_block is None:
+        P = decode_pages_per_block(K, block_size, hd, k_pages.dtype,
+                                   with_scales, nb)
+    else:
+        P = max(1, min(int(pages_per_compute_block), nb))
+    with_mask = block_mask is not None
     if block_mask is None:
         block_mask = jnp.ones((B, nb), jnp.int32)
 
-    # g-major regroup: (B, H, hd) -> (B, G, K, hd) -> (B*K, G, hd)
+    # g-major regroup: (B, H, hd) -> (B, G, K, hd) -> (B*K, G, hd); one
+    # program takes the K consecutive rows of its sequence
     qg = q.reshape(B, G, K, hd).transpose(0, 2, 1, 3).reshape(B * K, G, hd)
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, cap=cap, window=window,
-        block_size=block_size, num_kv_heads=K, pages_per_block=P,
-        table_width=nb, with_lse=return_lse, with_scales=with_scales)
+        block_size=block_size, pages_per_block=P, table_width=nb,
+        with_mask=with_mask, with_lse=return_lse, with_scales=with_scales)
 
-    out_specs = pl.BlockSpec((None, G, hd), lambda bk, j, *_: (bk, 0, 0))
+    row = pl.BlockSpec((K, G, hd), lambda b, *_: (b, 0, 0))
+    out_specs = row
     if return_lse:
         # partials stay fp32: they are re-weighted by exp(lse - m) in the
         # stitch, and rounding them to q.dtype first would make the
         # stitched result depend on the shard count
-        out_specs = (out_specs,
-                     pl.BlockSpec((None, G, 1), lambda bk, j, *_: (bk, 0, 0)))
+        out_specs = (row, pl.BlockSpec((K, G, 1), lambda b, *_: (b, 0, 0)))
         out_shape = (jax.ShapeDtypeStruct((B * K, G, hd), jnp.float32),
                      jax.ShapeDtypeStruct((B * K, G, 1), jnp.float32))
     else:
         out_shape = jax.ShapeDtypeStruct((B * K, G, hd), q.dtype)
 
-    page_specs = _page_specs(nb, P, K, block_size, hd, n_extra_scalars=0)
-    scale_specs, scale_operands = [], []
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    buffers = [pltpu.VMEM((2, P, K, block_size, hd), k_pages.dtype),
+               pltpu.VMEM((2, P, K, block_size, hd), v_pages.dtype)]
+    scale_operands = []
     if with_scales:
-        scale_specs = 2 * _page_specs(nb, P, K, block_size, 1,
-                                      n_extra_scalars=0)
-        scale_operands = [k_scale] * P + [v_scale] * P
+        # (N, K, bs, 1) -> one (1, lanes) row per page: Mosaic copies only
+        # rows whose last dim fills whole 128-lane tiles; the transpose in
+        # the kernel wants the buffer's rows padded to a multiple of 8
+        lanes = _scale_lanes(K, block_size)
+        scale_operands = [
+            jnp.pad(x.reshape(x.shape[0], 1, K * block_size),
+                    ((0, 0), (0, 0), (0, lanes - K * block_size)))
+            for x in (k_scale, v_scale)]
+        buffers += [pltpu.VMEM((2, -(-P // 8) * 8, 1, lanes),
+                               jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B * K, pl.cdiv(nb, P)),
-        in_specs=[
-            pl.BlockSpec((None, G, hd), lambda bk, j, *_: (bk, 0, 0)),
-            *page_specs,
-            *page_specs,
-            *scale_specs,
-        ],
+        grid=(B,),
+        in_specs=[row, hbm, hbm, *([hbm] * len(scale_operands))],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            *buffers,
+            pltpu.SemaphoreType.DMA((2, 2)),        # (k | v, slot)
+            pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, hd), jnp.float32),
         ],
     )
 
@@ -300,16 +395,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         out_shape=out_shape,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      block_mask.astype(jnp.int32), qg,
-      *([k_pages] * P), *([v_pages] * P), *scale_operands)
+      block_mask.astype(jnp.int32), qg, k_pages, v_pages, *scale_operands)
 
     if return_lse:
         o, lse = o
-        return (_head_major(o, B, K, G),
+        return (_head_major(o[..., :hd_in], B, K, G),
                 _head_major(lse[..., 0], B, K, G))
-    return _head_major(o, B, K, G)
+    return _head_major(o[..., :hd_in], B, K, G)
 
 
 def _chunk_kernel(bt_ref, ctx_ref, qlen_ref, mask_ref, q_ref, *rest, scale,
@@ -470,11 +564,10 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, ctx_lens,
     else:
         out_shape = jax.ShapeDtypeStruct((B * K, C, G, hd), q.dtype)
 
-    page_specs = _page_specs(nb, P, K, block_size, hd, n_extra_scalars=1)
+    page_specs = _page_specs(nb, P, K, block_size, hd)
     scale_specs, scale_operands = [], []
     if with_scales:
-        scale_specs = 2 * _page_specs(nb, P, K, block_size, 1,
-                                      n_extra_scalars=1)
+        scale_specs = 2 * _page_specs(nb, P, K, block_size, 1)
         scale_operands = [k_scale] * P + [v_scale] * P
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,

@@ -799,7 +799,8 @@ class InferenceEngine:
         about a microsecond when no trace is being captured), so a trace
         taken with ``jax.profiler.start_trace`` puts the host's work on the
         same clock as the device's operations: ``serve.step`` (the whole
-        call; stats ``step``, ``rows``, ``chunk_tokens``, ``full``) holds
+        call; stats ``step``, ``rows``, ``decode_pages`` (KV pages the
+        decode rows attend), ``chunk_tokens``, ``full``) holds
         ``serve.schedule`` (with ``serve.admit`` per admission),
         ``serve.copies`` (swaps, encodes, copy-on-write), ``serve.build``
         (host arrays, ``serve.h2d`` their transfer), ``serve.dispatch``,
@@ -876,7 +877,10 @@ class InferenceEngine:
                         for _, r in plan.decodes)
                     or any(r.sampling.needs_pipeline
                            for _, r, _ in plan.chunks))
+            bs = self.block_size
             span.set_metadata(rows=len(plan.decodes),
+                              decode_pages=sum(-(-r.context_len // bs)
+                                               for _, r in plan.decodes),
                               chunk_tokens=sum(n for *_, n in plan.chunks),
                               full=full)
             if on_step is not None:

@@ -23,17 +23,17 @@ from repro.serving.scheduler import Request, SamplingParams, Scheduler
 RNG = np.random.default_rng(0)
 
 
-def _paged_case(B, H, K, hd, bs, nblk, dtype):
+def _paged_case(B, H, K, hd, bs, nblk, dtype, rng=RNG):
     """Random page pools + disjoint per-seq block tables + ctx lens."""
     N = 1 + B * nblk
-    q = jnp.asarray(RNG.normal(0, 1, (B, H, hd)), jnp.float32).astype(dtype)
-    kp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
+    q = jnp.asarray(rng.normal(0, 1, (B, H, hd)), jnp.float32).astype(dtype)
+    kp = jnp.asarray(rng.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
-    vp = jnp.asarray(RNG.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
+    vp = jnp.asarray(rng.normal(0, 1, (N, bs, K, hd)).transpose(0, 2, 1, 3),
                      jnp.float32).astype(dtype)
-    perm = RNG.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
+    perm = rng.permutation(np.arange(1, N))[:B * nblk].reshape(B, nblk)
     bt = jnp.asarray(perm, jnp.int32)
-    ctx = jnp.asarray(RNG.integers(1, nblk * bs + 1, (B,)), jnp.int32)
+    ctx = jnp.asarray(rng.integers(1, nblk * bs + 1, (B,)), jnp.int32)
     return q, kp, vp, bt, ctx
 
 
@@ -91,6 +91,92 @@ def test_paged_inactive_slot_is_zero():
         assert np.all(np.isfinite(o))
 
 
+# The decode kernel loops over a row's live compute blocks only: cases for
+# where a context ends (mid-page, mid-block, at max_len), inactive rows,
+# every variant the kernel carries, and pages it must never read.
+# B, H, K, hd, bs, nblk, P, ctx, window, cap, kv_dtype, variant
+LIVE_CASES = {
+    "mid_page_mid_block_max_len": (
+        6, 4, 2, 16, 8, 6, 2, [5, 0, 20, 48, 0, 33], None, None, None,
+        None),
+    "window_cap_int8": (
+        4, 4, 2, 16, 8, 6, 2, [7, 48, 30, 0], 12, 30.0, "int8", None),
+    "stitched_shards": (
+        3, 4, 2, 16, 8, 6, 2, [48, 0, 21], None, None, None, "shards"),
+    # K_local 1 (a TP shard), and P left to the shapes (the whole table)
+    "fp8_k_local_1": (
+        3, 4, 1, 32, 8, 3, None, [17, 0, 24], 20, None, "fp8", None),
+    "dead_pages_never_read": (
+        5, 4, 2, 16, 8, 6, 2, [20, 0, 48, 26, 35], 10, None, None, "nan"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LIVE_CASES))
+def test_decode_kernel_live_blocks_vs_ref(name):
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.ref import paged_attention_partial_ref
+    from repro.models.attention import stitch_paged_partials
+    from repro.models.quant import dequantize_kv, quantize_kv
+    B, H, K, hd, bs, nblk, P, ctx, window, cap, kv, variant = \
+        LIVE_CASES[name]
+    # a generator of its own: the module's stream feeds the other tests
+    q, kp, vp, bt, _ = _paged_case(B, H, K, hd, bs, nblk, jnp.float32,
+                                   np.random.default_rng(15))
+    ctx = jnp.asarray(ctx, jnp.int32)
+    kw = dict(window=window, cap=cap)
+    o_r = paged_attention_ref(q, kp, vp, bt, ctx, **kw)
+    if variant == "nan":
+        # only pool rows a live entry names hold numbers (a page before
+        # the window, or a stale buffer, must not poison o), and table
+        # entries at or past cdiv(ctx, bs) name no pool row: reading one
+        # raises
+        first = np.maximum(np.asarray(ctx) - window, 0) // bs
+        n_live = -(-np.asarray(ctx) // bs)
+        live = {int(bt[b, e]) for b in range(B)
+                for e in range(first[b], n_live[b])}
+        dead = np.asarray([r not in live for r in range(kp.shape[0])])
+        kp = jnp.where(dead[:, None, None, None], jnp.nan, kp)
+        vp = jnp.where(dead[:, None, None, None], jnp.nan, vp)
+        past = np.arange(nblk)[None] >= n_live[:, None]
+        bt = jnp.where(past, kp.shape[0] + 7, bt)
+    if kv is not None:
+        kp, sk = quantize_kv(kp, kv)
+        vp, sv = quantize_kv(vp, kv)
+        # the oracle on the dequantized pools, in fp32 as the kernel runs
+        o_r = paged_attention_ref(
+            q, dequantize_kv(kp, sk).astype(jnp.float32),
+            dequantize_kv(vp, sv).astype(jnp.float32), bt, ctx, **kw)
+        kw.update(k_scale=sk, v_scale=sv)
+    # the TPU interpreter fills memory no DMA wrote with NaN
+    interp = pltpu.InterpretParams() if variant == "nan" else True
+    if variant == "shards":
+        # two shards, each holding alternate table entries, stitched
+        even = (np.arange(nblk)[None] % 2 == 0).repeat(B, 0)
+        parts = []
+        for m in (even, ~even):
+            mask = jnp.asarray(m, jnp.int32)
+            o_k, lse_k = paged_attention(
+                q, kp, vp, bt, ctx, block_mask=mask, return_lse=True,
+                interpret=interp, pages_per_compute_block=P, **kw)
+            o_p, lse_p = paged_attention_partial_ref(q, kp, vp, bt, ctx,
+                                                     mask, **kw)
+            np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_p),
+                                       atol=1e-5)
+            np.testing.assert_allclose(np.asarray(lse_k), np.asarray(lse_p),
+                                       atol=1e-5)
+            parts.append((o_k, lse_k))
+        o_k = stitch_paged_partials(jnp.stack([o for o, _ in parts]),
+                                    jnp.stack([lse for _, lse in parts]))
+    else:
+        o_k = paged_attention(q, kp, vp, bt, ctx, interpret=interp,
+                              pages_per_compute_block=P, **kw)
+    o_k = np.asarray(o_k, np.float32)
+    assert np.all(np.isfinite(o_k))
+    assert np.all(o_k[np.asarray(ctx) == 0] == 0)
+    np.testing.assert_allclose(o_k, np.asarray(o_r, np.float32), atol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # Multi-query (chunked prefill) kernel
 # ---------------------------------------------------------------------------
@@ -139,13 +225,22 @@ def test_chunk_kernel_vs_ref(case):
 
 
 def test_chunk_kernel_qlen1_matches_decode_kernel():
-    """A 1-token chunk is exactly a decode step."""
+    """A 1-token chunk is exactly a decode step over the same pages per
+    compute block (the decode kernel takes the whole table in one here)
+    and the same head width (it zero-pads the head dim to 128 lanes)."""
     B, H, K, hd, bs, nblk = 3, 4, 2, 16, 8, 4
     q, kp, vp, bt, ctx = _paged_case(B, H, K, hd, bs, nblk, jnp.float32)
     o_d = paged_attention(q, kp, vp, bt, ctx, interpret=True)
-    o_c = paged_prefill_attention(q[:, None], kp, vp, bt, ctx,
-                                  jnp.ones(B, jnp.int32), interpret=True)
-    np.testing.assert_array_equal(np.asarray(o_c)[:, 0], np.asarray(o_d))
+
+    def widen(x):
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, 128 - hd)])
+
+    o_c = paged_prefill_attention(widen(q)[:, None], widen(kp), widen(vp),
+                                  bt, ctx, jnp.ones(B, jnp.int32),
+                                  scale=hd ** -0.5, interpret=True,
+                                  pages_per_compute_block=nblk)
+    np.testing.assert_array_equal(np.asarray(o_c)[:, 0, :, :hd],
+                                  np.asarray(o_d))
 
 
 def test_chunk_ref_vs_dense_oracle():

@@ -107,6 +107,8 @@ def test_step_spans_nest_inside_serve_step(glm, mesh, tmp_path):
     for r in recs:
         st = stats[r.step]
         assert st["rows"] == len(r.decode_ctxs)
+        assert st["decode_pages"] == sum(-(-c // eng.block_size)
+                                         for c in r.decode_ctxs)
         assert st["chunk_tokens"] == sum(n for _, n in r.chunks)
         assert bool(st["full"]) == r.full
 

@@ -14,7 +14,10 @@ it exits.
 
 from __future__ import annotations
 
+import ast
 import os
+import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +97,12 @@ def _ragged(fused):
 KERNELS = {
     "paged_decode": lambda: _decode(jnp.bfloat16),
     "paged_decode_int8": lambda: _decode(jnp.int8),
+    # whisper's 64-wide heads, which the decode kernel pads to 128 lanes
+    "paged_decode_hd64": lambda: (
+        pa.paged_attention,
+        [((B, 20, 64), jnp.bfloat16), ((N, 20, BS, 64), jnp.bfloat16),
+         ((N, 20, BS, 64), jnp.bfloat16), ((B, NB), jnp.int32),
+         ((B,), jnp.int32)]),
     "paged_chunked_prefill": lambda: (
         pa.paged_prefill_attention,
         [((1, C, H, HD), jnp.bfloat16), ((N, K, BS, HD), jnp.bfloat16),
@@ -118,3 +127,44 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _roofline_pattern():
+    """``PATTERN`` of the benchmark's ``paged_decode_roofline`` metric, read
+    from its file (importing it would need the harness's package)."""
+    path = (pathlib.Path(__file__).parents[1] / "benchmarks" / "chip" /
+            "metrics" / "paged_decode_roofline.py")
+    for node in ast.parse(path.read_text()).body:
+        if isinstance(node, ast.Assign) and \
+                getattr(node.targets[0], "id", None) == "PATTERN":
+            return ast.literal_eval(node.value)
+    raise AssertionError(f"no PATTERN in {path}")
+
+
+# the benchmark cells' decode shapes: starcoder2_3b.ide_completion (batch
+# 16, 24/2 heads) and glm4_9b-pp2.chat_batch (batch 32, 32/2 heads), both
+# max_len 4096 at block 16
+CELL_DECODES = {"ide_completion": (16, 24), "chat_batch": (32, 32)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_DECODES))
+def test_decode_kernel_keeps_the_roofline_signature(cell, one_chip):
+    """The compiled decode call at a cell's shapes is the custom call the
+    benchmark's roofline metric matches in the device trace, whose event
+    names print the operands with their shapes."""
+    from jax._src.lib import _jax
+    b, h = CELL_DECODES[cell]
+    nb, n = 256, 256 * b + 1
+    shapes = [((b, h, HD), jnp.bfloat16), ((n, K, BS, HD), jnp.bfloat16),
+              ((n, K, BS, HD), jnp.bfloat16), ((b, nb), jnp.int32),
+              ((b,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(pa.paged_attention).lower(*args).compile()
+    opts = _jax.HloPrintOptions()
+    opts.print_operand_shape = True
+    text = "\n".join(m.to_string(opts)
+                     for m in compiled.runtime_executable().hlo_modules())
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert re.search(_roofline_pattern(), calls[0]), calls[0][:300]
