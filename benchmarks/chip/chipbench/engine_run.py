@@ -29,8 +29,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from chipbench import counts, reference, trace, traffic, weights
-from chipbench.spec import Cell
+from chipbench import counts, trace, traffic, weights
+from chipbench.spec import Cell, load_reference
 
 
 @dataclass
@@ -152,14 +152,41 @@ class CompileCounter:
 # -- building -----------------------------------------------------------------
 
 
+def _plain(v):
+    """A config value as JSON has it: tuples as lists."""
+    return [_plain(x) for x in v] if isinstance(v, (tuple, list)) else v
+
+
+# the program's layer kinds in the configuration file's words (HF's)
+LAYER_TYPES = {"attn": "full", "local": "sliding"}
+
+
+def _attr(cfg, path: str):
+    """``cfg``'s attribute at a dotted path; None below a None group."""
+    for part in path.split("."):
+        if cfg is None:
+            return None
+        if not hasattr(cfg, part):
+            raise ValueError(f"the program's config has no {path!r}")
+        cfg = getattr(cfg, part)
+    return cfg
+
+
 def program_config(cell: Cell):
     """The program's ModelConfig for the cell's configuration file, checked
-    against the file's sizes."""
+    against the file's sizes: widths, layer kinds and window, experts.
+    Sizes that state no ``layer_types``, ``window`` or ``moe`` ask for a
+    dense program with full attention in every layer. ``program.expect``
+    adds ModelConfig attribute paths (``"attn_logit_softcap"``,
+    ``"moe.capacity_factor"``) and the values they must hold, for fields
+    that the sizes do not state."""
     import dataclasses
     from repro.config import get_config
     prog = cell.config["program"]
     cfg = get_config(prog["arch"], smoke=prog.get("smoke", False))
-    cfg = dataclasses.replace(cfg, **prog.get("overrides", {}))
+    cfg = dataclasses.replace(cfg, **{
+        k: tuple(v) if isinstance(v, list) else v
+        for k, v in prog.get("overrides", {}).items()})
     sz = cell.config["sizes"]
     want = {"num_layers": sz["layers"], "d_model": sz["d_model"],
             "num_heads": sz["heads"], "num_kv_heads": sz["kv_heads"],
@@ -167,10 +194,27 @@ def program_config(cell: Cell):
             "vocab_size": sz["vocab"], "tie_embeddings": sz["tied"],
             "norm": sz["norm"], "rope_theta": sz["rope_theta"],
             "mlp_activation": {"gelu": "gelu_mlp",
-                               "swiglu": "silu"}[sz["mlp"]]}
-    bad = {k: (getattr(cfg, k), v) for k, v in want.items()
-           if getattr(cfg, k) != v}
-    if bad or cfg.block_pattern != ("attn",) or cfg.sliding_window:
+                               "swiglu": "silu"}[sz["mlp"]],
+            "sliding_window": sz.get("window")}
+    moe = sz.get("moe")
+    if moe is None:
+        want["moe"] = None
+    else:
+        want.update({"moe.num_experts": moe["experts"],
+                     "moe.experts_per_token": moe["experts_per_token"],
+                     "moe.d_ff_expert": moe["d_ff_expert"]})
+    expect = prog.get("expect", {})
+    twice = [p for p in expect if p in want or p == "block_pattern"]
+    if twice:
+        raise ValueError(f"{cell.config_name}'s program.expect names "
+                         f"{twice}, which its sizes state")
+    want.update(expect)
+    bad = {k: (got, v) for k, v in want.items()
+           if _plain(got := _attr(cfg, k)) != v}
+    kinds = [LAYER_TYPES.get(k, k) for k in cfg.layer_kinds()]
+    if kinds != sz.get("layer_types", ["full"] * sz["layers"]):
+        bad["layer_types"] = (kinds, sz.get("layer_types"))
+    if bad:
         raise ValueError(f"program config differs from {cell.config_name}:"
                          f" {bad}")
     return cfg
@@ -389,10 +433,11 @@ def _log_window(d: RunData, log) -> None:
 
 def check_outputs(cell: Cell, params, recs: list, seed: int,
                   control: bool, log) -> dict:
-    """Compare a sample of the finished greedy requests with the plain
-    reference: the widest gap by which a served token's logit lies below
-    the reference's best."""
+    """Compare a sample of the finished greedy requests with the
+    configuration's plain reference: the widest gap by which a served
+    token's logit lies below the reference's best."""
     chk = cell.check
+    reference = load_reference(cell)
     done = [r for r in recs if r.finished and r.item.greedy]
     if not done:
         return {"requests": 0, "tokens": 0, "logit_gap": None}

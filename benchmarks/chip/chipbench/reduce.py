@@ -56,20 +56,25 @@ def mfu(run, steps, seconds: float):
 def roofline(run, pattern: str, work) -> float | None:
     """Least time for the work ÷ summed device time of the kernel events
     whose name matches ``pattern``, %, over the whole steps of the traced
-    slice. ``work(step) -> (flops, bytes)`` of one call; there is one call
-    per layer of every step where the work is nonzero."""
+    slice. ``work(step)`` gives one call's work per layer kind,
+    ``{kind: (flops, bytes)}`` as ``counts.Model``'s kernels count it, or
+    one ``(flops, bytes)`` for every layer; there is one call per layer of
+    every step where the work is nonzero."""
     if run.trace is None:
         return None
     rx = re.compile(pattern)
     least, spent = 0.0, 0
     for rec, ops in run.traced_steps():
-        f, b = work(rec)
+        w = work(rec)
+        per_kind = w.items() if isinstance(w, dict) else [(None, w)]
         mine = [o for o in ops if rx.search(o.name)]
-        if not mine or f == 0:
+        if not mine or all(fb[0] == 0 for _, fb in per_kind):
             continue
-        t, _ = counts.roofline_s(f, b, run.peaks["bf16_flops"],
-                                 run.peaks["hbm_bytes_per_s"])
-        least += t * run.model.layers
+        for kind, (f, b) in per_kind:
+            t, _ = counts.roofline_s(f, b, run.peaks["bf16_flops"],
+                                     run.peaks["hbm_bytes_per_s"])
+            least += t * (run.model.layers if kind is None
+                          else run.model.kinds[kind])
         spent += sum(o.dur for o in mine)
     if spent == 0:
         return None

@@ -16,11 +16,20 @@ the weights) and the K/V rows rounded to fp8 per row: the next precision
 below the configuration's bf16. fp8 values are exact in bf16, so its
 weight matmuls are one bf16 pass with the scales applied after. It exists
 to show that the comparison fails a lower-precision program.
+
+This is the default reference of a configuration. One whose file names
+another (``"reference": "references/<name>.py"``) is compared against that
+module's ``gaps`` instead; such a module may build on the pieces here
+(``_mm``, ``_norm``, ``_rope``, ``_attention`` with its ``window``,
+``_layer``, ``served_gaps``). ``gaps`` here refuses sizes that describe
+more than this dense, full-attention block, so such a configuration is
+never compared against it by default.
 """
 
 from __future__ import annotations
 
 import functools
+import json
 import math
 
 import jax
@@ -31,6 +40,9 @@ Q_BLOCK = 512             # attention queries per block (bounds the scores)
 BUCKET = 1024             # sequences are padded to a multiple of this
 V_BLOCKS = 16             # the LM head is read in this many row blocks
 BF = jnp.bfloat16
+# the sizes this block implements
+DENSE_SIZES = {"layers", "d_model", "heads", "kv_heads", "head_dim", "d_ff",
+               "vocab", "mlp", "norm", "norm_eps", "tied", "rope_theta"}
 
 
 FP8_MAX = 448.0           # largest finite float8_e4m3fn
@@ -89,9 +101,10 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
-def _attention(q, k, v):
+def _attention(q, k, v, window=None):
     """Causal attention. q (T, H, hd); k, v (T, K, hd); head h reads kv
-    head h mod K. Queries go in blocks of Q_BLOCK."""
+    head h mod K; with a ``window``, a query sees the ``window`` keys up
+    to its own. Queries go in blocks of Q_BLOCK."""
     T, H, hd = q.shape
     K = k.shape[1]
     kh = jnp.tile(k, (1, H // K, 1))          # head h -> kv head h % K
@@ -102,7 +115,10 @@ def _attention(q, k, v):
         qb = jax.lax.dynamic_slice_in_dim(q, i * Q_BLOCK, Q_BLOCK)
         s = jnp.einsum("qhd,khd->hqk", qb, kh, precision=HI) / math.sqrt(hd)
         rows = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        s = jnp.where(keys[None, None, :] <= rows[None, :, None], s, -jnp.inf)
+        seen = keys[None, None, :] <= rows[None, :, None]
+        if window is not None:
+            seen &= keys[None, None, :] > rows[None, :, None] - window
+        s = jnp.where(seen, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("hqk,khd->qhd", p, vh, precision=HI)
 
@@ -110,7 +126,7 @@ def _attention(q, k, v):
     return out.reshape(T, H, hd)
 
 
-def _layer(sz, control, x, lp):
+def _layer(sz, control, x, lp, window=None):
     f32 = lambda t: t.astype(jnp.float32)          # noqa: E731
     lp = jax.tree.map(f32, lp)
     T, d = x.shape
@@ -124,7 +140,7 @@ def _layer(sz, control, x, lp):
     q, k = _rope(q, pos, sz["rope_theta"]), _rope(k, pos, sz["rope_theta"])
     if control:
         k, v = _fp8(k, -1), _fp8(v, -1)
-    o = _attention(q, k, v).reshape(T, H * hd)
+    o = _attention(q, k, v, window).reshape(T, H * hd)
     x = x + _mm(o, a["wo"].reshape(H * hd, d), control)
     h = _norm(x, lp["norm2"], sz["norm"], sz["norm_eps"])
     m = lp["mlp"]
@@ -145,11 +161,16 @@ def hidden(params, tokens, sz, control=False):
     return _norm(x, fn, sz["norm"], sz["norm_eps"])
 
 
-@functools.partial(jax.jit, static_argnames=("sz_items", "control"))
-def _gaps(params, tokens, positions, served, sz_items, control):
-    sz = dict(sz_items)
-    h_ref = hidden(params, tokens, sz)[positions]
-    h_ctl = hidden(params, tokens, sz, True)[positions] if control else None
+@functools.partial(jax.jit, static_argnames=("sz_json", "control",
+                                             "hidden_fn"))
+def _gaps(params, tokens, positions, served, sz_json, control,
+          hidden_fn=hidden):
+    """(served gaps, control gaps) at the given positions; ``sz_json`` is
+    the sizes as JSON, a hashable static argument."""
+    sz = json.loads(sz_json)
+    h_ref = hidden_fn(params, tokens, sz)[positions]
+    h_ctl = hidden_fn(params, tokens, sz, True)[positions] if control \
+        else None
     head = params["embed"]["table" if sz["tied"] else "head"]
     V, d = head.shape
     blocks = head.reshape(V_BLOCKS, V // V_BLOCKS, d)
@@ -187,8 +208,27 @@ def bucket(n: int) -> int:
     return -(-n // BUCKET) * BUCKET
 
 
+def _dense_only(sz: dict) -> None:
+    """Refuse sizes that state layer kinds or experts this block lacks."""
+    extra = set(sz) - DENSE_SIZES - {"layer_types"}
+    if extra or set(sz.get("layer_types", ())) - {"full"}:
+        raise ValueError("the dense reference does not implement "
+                         f"{sorted(extra) or 'these layer kinds'}: the "
+                         "configuration has to name its own reference")
+
+
 def gaps(params, seq, n_prompt, sz: dict, control: bool = False,
          t_len: int | None = None, p_len: int = 128):
+    """``served_gaps`` of the dense block, for sizes that state nothing
+    more than it implements."""
+    _dense_only(sz)
+    return served_gaps(hidden, params, seq, n_prompt, sz, control, t_len,
+                       p_len)
+
+
+def served_gaps(hidden_fn, params, seq, n_prompt, sz: dict,
+                control: bool = False, t_len: int | None = None,
+                p_len: int = 128):
     """For a request with prompt ``seq[:n_prompt]`` and served tokens
     ``seq[n_prompt:]``: at each served token, how far its logit lies below
     the reference's best (0 where it is the reference's argmax); with
@@ -196,7 +236,8 @@ def gaps(params, seq, n_prompt, sz: dict, control: bool = False,
     sequence is padded to ``t_len`` tokens (a multiple of Q_BLOCK; by
     default its bucket) and the served positions to ``p_len``; causality
     keeps the padding out. Returns numpy arrays (served gaps, control gaps
-    or None)."""
+    or None). ``hidden_fn(params, tokens, sz, control)`` gives the
+    final-normed hidden states of the whole sequence."""
     import numpy as np
     seq = np.asarray(seq, np.int32)
     n_out = len(seq) - n_prompt
@@ -210,6 +251,6 @@ def gaps(params, seq, n_prompt, sz: dict, control: bool = False,
     served[:n_out] = seq[n_prompt:]
     with jax.default_matmul_precision("highest"):
         g, c = _gaps(params, tokens, positions, served,
-                     tuple(sorted(sz.items())), control)
+                     json.dumps(sz, sort_keys=True), control, hidden_fn)
     g = np.asarray(g)[:n_out]
     return g, (np.asarray(c)[:n_out] if control else None)

@@ -3,7 +3,11 @@
 Everything a cell needs is a file of its own under ``benchmarks/chip``:
 
   cells/<workload>.json    engine settings and offered load of one cell
-  configs/<config>.json    the model's sizes as run, with its source
+  configs/<config>.json    the model's sizes as run, with its source; the
+                           program fields its sizes do not state
+                           (``program.expect``) and, where the dense one
+                           does not fit, its own reference
+                           (``"reference": "references/<x>.py"``)
   traffic/<traffic>.json   parameters of the one traffic generator
   metrics/<metric>.py      a reader of one metric (``read(run)``)
 
@@ -36,6 +40,7 @@ class Cell:
 
     def __init__(self, name: str, root: Path = ROOT, bench: dict | None = None):
         self.name = name
+        self.root = root
         path = root / "cells" / f"{name}.json"
         if not path.is_file():
             raise FileNotFoundError(f"no cell file {path}")
@@ -69,11 +74,33 @@ def metrics_for(bench: dict, workload: str, trace: bool) -> list[dict]:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def load_reader(name: str, root: Path = ROOT):
-    """The ``read(run)`` function of ``metrics/<name>.py``."""
-    path = root / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+def _load_module(name: str, path: Path):
+    """The Python file at ``path``, loaded as a module named ``name``."""
+    name = name.replace("/", "_").replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str, root: Path = ROOT):
+    """The ``read(run)`` function of ``metrics/<name>.py``."""
+    return _load_module("chipbench_metric_" + name,
+                        root / "metrics" / f"{name}.py").read
+
+
+def load_reference(cell: Cell):
+    """The plain reference the cell's configuration is compared against: the
+    module its ``"reference"`` key names (a path under the benchmark's
+    root, holding ``gaps`` and ``bucket`` as ``chipbench/reference.py``
+    does), or ``chipbench/reference.py``."""
+    rel = cell.config.get("reference")
+    if rel is None:
+        from chipbench import reference
+        return reference
+    path = (cell.root / rel).resolve()
+    if not path.is_file() or not path.is_relative_to(cell.root.resolve()):
+        raise FileNotFoundError(f"{cell.config_name}'s reference {rel} is "
+                                f"not a file under {cell.root}")
+    return _load_module("chipbench_reference_" + rel.removesuffix(".py"),
+                        path)

@@ -15,9 +15,15 @@ import math
 import jax
 import jax.numpy as jnp
 
-# leaf name -> number of leading axes (after the layer axis) summed over
-FAN_IN_AXES = {"wq": 1, "wk": 1, "wv": 1, "wo": 2,
-               "w_in": 1, "w_gate": 1, "w_out": 1}
+# (parent, leaf name) -> the axes (after the layer axis) a weight's fan-in
+# spans: its input width. Attention (d, heads, hd) and (heads, hd, d); dense
+# MLP (d, f) and (f, d); experts (E, d, f) and (E, f, d), router (d, E).
+FAN_IN_AXES = {("attn", "wq"): (0,), ("attn", "wk"): (0,),
+               ("attn", "wv"): (0,), ("attn", "wo"): (0, 1),
+               ("mlp", "w_in"): (0,), ("mlp", "w_gate"): (0,),
+               ("mlp", "w_out"): (0,),
+               ("moe", "router"): (0,), ("moe", "w_in"): (1,),
+               ("moe", "w_gate"): (1,), ("moe", "w_out"): (1,)}
 EMBED_STD = 0.02
 NORM_STD = 0.1
 
@@ -28,7 +34,15 @@ def base_key(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def _leaf(key, name: str, shape, stacked: bool):
+def _leaf(key, path: tuple[str, ...], shape, stacked: bool):
+    """One leaf at ``path`` (the tree's keys down to it): norms near 1,
+    biases near 0, the embedding at EMBED_STD, every matrix at
+    1/sqrt(fan-in)."""
+    name = path[-1]
+    axes = FAN_IN_AXES.get(path[-2:])
+    if axes is None and name not in ("scale", "bias", "table", "head"):
+        raise KeyError(f"no weight rule for the leaf {'/'.join(path)}")
+
     def draw(k, shp):
         z = jax.random.normal(k, shp, jnp.float32)
         if name == "scale":
@@ -39,8 +53,7 @@ def _leaf(key, name: str, shape, stacked: bool):
             return EMBED_STD * z
         if name == "head":
             return z / math.sqrt(shp[-1])
-        n = FAN_IN_AXES[name]
-        return z / math.sqrt(math.prod(shp[:n]))
+        return z / math.sqrt(math.prod(shp[a] for a in axes))
 
     if not stacked:
         return draw(key, shape).astype(jnp.bfloat16)
@@ -58,7 +71,7 @@ def make_params(shapes, seed: int):
         leaves = []
         for i, (path, s) in enumerate(paths):
             keys = [getattr(p, "key", str(p)) for p in path]
-            leaves.append(_leaf(jax.random.fold_in(key, i), keys[-1],
+            leaves.append(_leaf(jax.random.fold_in(key, i), tuple(keys),
                                 s.shape, stacked="blocks" in keys))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 

@@ -9,13 +9,15 @@ page pool. For each of the cell's step programs (with and without a chunk;
 with the full sampling pipeline too where the traffic samples) it prints
 the bytes, and compiles the plain step at a second pool size to show
 whether the temporaries grow with the pool. ``--reference`` also compiles
-the plain reference at the cell's longest sequence.
+the plain reference at the cell's longest sequence (the configuration's
+own reference module, through its ``hidden``).
 """
 
 from __future__ import annotations
 
 import argparse
 import functools
+import json
 import os
 import sys
 from pathlib import Path
@@ -112,11 +114,12 @@ def main() -> int:
     if args.reference:
         T = -(-e["max_len"] // 1024) * 1024
         P = -(-cell.traffic["output"]["max"] // 128) * 128
-        sz = cell.config["sizes"]
+        sz_json = json.dumps(cell.config["sizes"], sort_keys=True)
+        hidden = spec.load_reference(cell).hidden
         for control in (False, True):
             fn = functools.partial(reference._gaps.__wrapped__,
-                                   sz_items=tuple(sorted(sz.items())),
-                                   control=control)
+                                   sz_json=sz_json, control=control,
+                                   hidden_fn=hidden)
             i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32,  # noqa
                                                  sharding=rep)
             with jax.default_matmul_precision("highest"):

@@ -3,8 +3,9 @@ functions: Pallas kernels in interpret mode, the chip check skipped, and
 everything else as on the chip — weights, engine, warm-up, window, trace
 reduction, metric readers, the reference comparison. Also: the command
 itself exits non-zero off the chip, a cell dropped into a ``cells/``
-directory is found by name, the fp8 control fails the comparison, and a
-token altered where it is produced makes ``correct`` false.
+directory is found by name, the fp8 control fails the comparison, a token
+altered where it is produced makes ``correct`` false, and a configuration
+with windowed layers and its own reference file is taken with files only.
 """
 
 from __future__ import annotations
@@ -12,6 +13,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import shutil
 import subprocess
 import sys
 from pathlib import Path
@@ -29,6 +31,8 @@ from chipbench import engine_run, reference, spec  # noqa: E402
 SMOKE = "smoke.sessions"
 SMOKE_BATCH = "smoke.batch"
 SMOKE_WIDE = "smoke.wide"
+SMOKE_WINDOWED = "smoke.windowed"
+WINDOW = 16
 # the smoke model's served tokens lie within 1e-3 of the reference's best
 # (bf16 against float32); a wrong token lies ~0.1 below it
 LIMIT = 0.02
@@ -85,8 +89,23 @@ def smoke_tree(root: Path) -> dict:
                                       "vocab_size": 4096}}}
     cells[SMOKE_WIDE] = dict(cells[SMOKE], config="wide",
                              check=dict(check, max_requests=8, tokens=64))
+    # wide, with a sliding layer and a full one: the program is told its
+    # layer kinds and window, checks them against the sizes, and is
+    # compared against the reference file the configuration names
+    kinds = {"block_pattern": ["local", "attn"], "sliding_window": WINDOW}
+    windowed = {
+        "sizes": dict(wide["sizes"], layer_types=["sliding", "full"],
+                      window=WINDOW),
+        "program": dict(wide["program"],
+                        overrides=dict(wide["program"]["overrides"], **kinds)),
+        "reference": "references/windowed.py"}
+    cells[SMOKE_WINDOWED] = dict(cells[SMOKE_WIDE], config="windowed")
+    (root / "references").mkdir(exist_ok=True)
+    shutil.copy(HERE / "testdata" / "windowed_reference.py",
+                root / "references" / "windowed.py")
     (root / "configs" / "smoke.json").write_text(json.dumps(cfg))
     (root / "configs" / "wide.json").write_text(json.dumps(wide))
+    (root / "configs" / "windowed.json").write_text(json.dumps(windowed))
     (root / "traffic" / "sessions.json").write_text(json.dumps(sessions))
     (root / "traffic" / "batch.json").write_text(json.dumps(batch))
     for name, c in cells.items():
@@ -97,7 +116,9 @@ def smoke_tree(root: Path) -> dict:
         {"name": SMOKE_BATCH, "config": "smoke", "traffic": "batch",
          "chips": 1},
         {"name": SMOKE_WIDE, "config": "wide", "traffic": "sessions",
-         "chips": 1}])
+         "chips": 1},
+        {"name": SMOKE_WINDOWED, "config": "windowed",
+         "traffic": "sessions", "chips": 1}])
     for kind in ("end_to_end", "per_layer"):
         bench[kind] = [dict(m, workloads=[SMOKE]) if "workloads" in m
                        else m for m in bench[kind]]
@@ -150,6 +171,37 @@ def test_fp8_control_fails_the_comparison(smoke):
     _, res, _ = _run(root, bench, SMOKE_WIDE, 11, False, control=True)
     assert res["check"]["logit_gap"] <= LIMIT
     assert res["check"]["control_gap"] > LIMIT
+
+
+def test_windowed_configuration_with_its_own_reference(smoke):
+    """A configuration with a sliding layer, its program's layer kinds and
+    window checked against its sizes, and its own reference file, runs
+    correct through the whole harness; the fp8 control fails it; and the
+    same served tokens fail a reference without the window, so the window
+    is what it compares."""
+    from chipbench import weights
+    from repro.models import api
+    root, bench = smoke
+    seed = 2 ** 32 + 13
+    cell, res, line = _run(root, bench, SMOKE_WINDOWED, seed, False,
+                           control=True)
+    assert line["correct"], line["check"]
+    assert res["check"]["control_gap"] > LIMIT
+    ref = spec.load_reference(cell)
+    assert ref is not reference and ref.bucket(1) == 1024
+    cfg = engine_run.program_config(cell)
+    assert cfg.layer_kinds() == ("local", "attn")
+    params = weights.make_params(api.abstract_params(cfg)[0], seed)
+    sz = cell.config["sizes"]
+    unwindowed = dict(sz, window=10 ** 6)
+    done = [r for r in res["data"].recs if r.finished]
+    assert max(len(r.item.prompt) for r in done) > 4 * WINDOW
+    worst = {}
+    for name, s in (("windowed", sz), ("full", unwindowed)):
+        worst[name] = max(float(ref.gaps(
+            params, np.concatenate([r.item.prompt, r.tokens]),
+            len(r.item.prompt), s)[0].max()) for r in done)
+    assert worst["windowed"] <= LIMIT < worst["full"], worst
 
 
 def test_altered_token_makes_the_run_incorrect(smoke, monkeypatch):

@@ -111,13 +111,44 @@ def test_roofline_share_over_the_recorded_steps(chip_trace):
                   chip_trace, *trace.window(chip_trace))
     dec = reduce.roofline(run, _pattern("paged_decode_roofline"),
                           lambda s: model.decode_kernel(s.decode_ctxs))
-    f, b = model.decode_kernel((2048,) * 16)
+    f, b = model.decode_kernel((2048,) * 16)["full"]
     least = 3 * 30 * max(f / 197e12, b / 819e9)
     assert dec == pytest.approx(100 * least / 0.144479949, rel=1e-6)
     pre = reduce.roofline(
         run, _pattern("paged_prefill_roofline"),
         lambda s: model.chunk_kernel(*s.chunk) if s.chunk else (0, 0))
-    f, b = model.chunk_kernel(0, 256)
+    f, b = model.chunk_kernel(0, 256)["full"]
     least = 2 * 30 * max(f / 197e12, b / 819e9)
     assert pre == pytest.approx(100 * least / 0.1255249, rel=1e-6)
     assert reduce.roofline(run, r"no such kernel", lambda s: (1, 1)) is None
+
+
+def test_roofline_sums_each_layer_kind_s_least_time():
+    """3 sliding layers and 1 full: the least time of a step's calls is
+    3 × that of a sliding call + 1 × that of a full one, not 4 × the
+    full one's."""
+    model = counts.Model(layers=4, d=2304, heads=32, kv_heads=4,
+                         head_dim=128, d_ff=7168, vocab=98304, gated=True,
+                         layer_types=("sliding",) * 3 + ("full",),
+                         window=1024)
+    peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    ctxs = (4096,) * 8
+    kernel = ("%k = bf16[32,8,128]{2,1,0} custom-call(s32[8,256]{1,0} %a, "
+              "s32[8]{0} %b, s32[8,256]{1,0} %c, bf16[8,32,128] %q), "
+              'custom_call_target="tpu_custom_call"')
+    spent_ns = 400_000
+    tr = trace.Trace([[E(kernel, 10, 10 + spent_ns)]],
+                     [E("bench.step", 0, 1_000_000, {"step": 0})])
+    run = RunData(model, peaks, 1.0, 0.0, 0.0, 1.0,
+                  [StepRec(0, 0, 0, ctxs, None, False, False, 0, 0, 0)], [],
+                  False, tr, 0, 1_000_000)
+    got = reduce.roofline(run, _pattern("paged_decode_roofline"),
+                          lambda s: model.decode_kernel(s.decode_ctxs))
+    work = model.decode_kernel(ctxs)
+    least = sum(n * counts.roofline_s(*work[k], 197e12, 819e9)[0]
+                for k, n in (("sliding", 3), ("full", 1)))
+    assert got == pytest.approx(100 * least / (spent_ns / 1e9), rel=1e-12)
+    # the window cuts each sliding call's KV bytes by 4 at context 4,096:
+    # counting 4 full calls would overstate the least time by 16 / 7
+    full_only = 4 * counts.roofline_s(*work["full"], 197e12, 819e9)[0]
+    assert least == pytest.approx(full_only * 7 / 16, rel=1e-2)
