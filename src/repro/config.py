@@ -34,7 +34,29 @@ class MoEConfig:
     d_ff_expert: int
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
-    # n_shared_experts etc. could go here; none of the assigned archs need it.
+    # Experts [0, experts_held) of the num_experts the router scores live in
+    # this model's expert leaves: one chip's share of expert parallelism.
+    # None holds them all.
+    experts_held: int | None = None
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071) of the full-attention layers:
+    frequencies below the ``beta_slow`` .. ``beta_fast`` rotation band at
+    ``original_max_positions`` are divided by ``factor``, those above it
+    kept, a linear ramp between; cos and sin are scaled by
+    ``attention_factor``."""
+    factor: float
+    original_max_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -67,6 +89,7 @@ class ModelConfig:
     # --- attention options -------------------------------------------------
     rope_theta: float = 10000.0
     rope_sections: tuple[int, ...] | None = None   # M-RoPE (qwen2-vl): (t,h,w)
+    rope_scaling: YarnScaling | None = None         # full ("attn") layers only
     qk_norm: bool = False                           # qwen3 family
     attn_logit_softcap: float | None = None         # gemma2 (50.0), grok
     final_logit_softcap: float | None = None        # gemma2 (30.0)
@@ -96,6 +119,13 @@ class ModelConfig:
     # --- numerics -------------------------------------------------------------
     dtype: str = "bfloat16"
     sub_quadratic: bool = False      # eligible for long_500k
+
+    def __post_init__(self):
+        # groups given as dicts (a JSON override) become their dataclasses
+        for name, kind in (("moe", MoEConfig), ("rope_scaling", YarnScaling)):
+            v = getattr(self, name)
+            if isinstance(v, dict):
+                object.__setattr__(self, name, kind(**v))
 
     @property
     def padded_vocab_size(self) -> int:
@@ -145,8 +175,9 @@ class ModelConfig:
         m = self.moe
         full = self.param_count()
         per_expert = 3 * self.d_model * m.d_ff_expert
-        inactive = (m.num_experts - m.experts_per_token) * per_expert
-        return full - self.num_layers * inactive
+        # of the held experts, a token meets k * held / E on average
+        active = m.experts_per_token * m.held // m.num_experts
+        return full - self.num_layers * (m.held - active) * per_expert
 
     def _attn_params(self) -> int:
         d = self.d_model
@@ -156,7 +187,7 @@ class ModelConfig:
         if self.moe is not None:
             m = self.moe
             return self.d_model * m.num_experts + (
-                m.num_experts * 3 * self.d_model * m.d_ff_expert
+                m.held * 3 * self.d_model * m.d_ff_expert
             )
         mats = 2 if self.mlp_activation == "gelu_mlp" else 3
         return mats * self.d_model * self.d_ff
@@ -262,6 +293,7 @@ ARCHS: tuple[str, ...] = (
     "qwen3_moe_30b_a3b",
     "grok1_314b",
     "mamba2_370m",
+    "mellum2_12b_a2p5b",
 )
 
 # Accept dashed ids from the assignment table as aliases.
@@ -276,6 +308,7 @@ _ALIASES = {
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "grok-1-314b": "grok1_314b",
     "mamba2-370m": "mamba2_370m",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2p5b",
 }
 
 

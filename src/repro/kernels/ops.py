@@ -19,6 +19,7 @@ from __future__ import annotations
 import os
 
 import jax
+import jax.numpy as jnp
 
 
 def _use_pallas() -> str | None:
@@ -235,3 +236,59 @@ def embedding_gather(table, ids):
         from repro.kernels import embedding as emb
         return emb.gather(table, ids, interpret=(mode == "interpret"))
     return table[ids]
+
+
+# a grouped-matmul grid step holds one (tk, tn) tile of a group's matrix in
+# VMEM, double-buffered; this bounds it (v5e scopes 16 MiB per kernel)
+GMM_RHS_TILE_BYTES = 4 * 2 ** 20
+
+
+def _lane_tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``dim`` and is at most
+    ``cap``; ``dim`` itself where it is no multiple of 128 (a block may
+    span a whole axis)."""
+    if dim % 128:
+        return dim
+    return max(t for t in range(128, min(dim, max(cap, 128)) + 1, 128)
+               if dim % t == 0)
+
+
+def gmm_tiling(m: int, k: int, n: int, itemsize: int) -> tuple[int, int, int]:
+    """(tm, tk, tn) of megablox ``gmm`` for an (m, k) by (G, k, n) product.
+    tm is the MXU's 128 rows, or m rounded up to 16 (a bf16 sublane pair)
+    where fewer rows come; tn spans n up to 2048 lanes; tk is as deep as
+    GMM_RHS_TILE_BYTES allows, so most products run one grid step per m
+    tile a group touches."""
+    tm = min(128, -(-m // 16) * 16)
+    tn = _lane_tile(n, 2048)
+    tk = _lane_tile(k, GMM_RHS_TILE_BYTES // (tn * itemsize))
+    return tm, tk, tn
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, out_dtype=jnp.float32):
+    """Rows of ``lhs`` (m, k), sorted by group, each times its group's
+    matrix of ``rhs`` (G, k, n): group g owns the ``group_sizes[g]`` rows
+    after those of groups 0 .. g-1. Rows past ``sum(group_sizes)`` belong
+    to no group and are never multiplied; their output is undefined
+    (zero off the TPU), so the caller masks them.
+
+    On a TPU (and under ``REPRO_FORCE_PALLAS=interpret``) megablox ``gmm``,
+    which visits only the m tiles some group covers, at ``gmm_tiling``'s
+    tiles; lhs is padded to a whole m tile. Elsewhere
+    ``jax.lax.ragged_dot``. Accumulates in float32."""
+    mode = _use_pallas()
+    if mode is not None:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        m, k = lhs.shape
+        tiling = gmm_tiling(m, k, rhs.shape[2], rhs.dtype.itemsize)
+        pad = -m % tiling[0]
+        if pad:
+            lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+        # positional: gmm is a custom_vjp whose static arguments are
+        # numbered (out dtype, tiling, group_offset, existing_out,
+        # transpose_rhs, interpret)
+        out = gmm(lhs, rhs, group_sizes, out_dtype, tiling, None, None,
+                  False, mode == "interpret")
+        return out[:m]
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=out_dtype)

@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -55,16 +57,40 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
                             / head_dim))
 
 
+def yarn_freqs(head_dim: int, theta: float, scaling) -> jnp.ndarray:
+    """YaRN's inverse frequencies (``scaling``: a ``config.YarnScaling``),
+    as the published definition computes them: the band between the
+    dimensions that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max_positions`` (bounds truncated to integers, floor and
+    ceil) ramps linearly from the plain frequency to it over ``factor``."""
+    def dim_at(rotations):
+        return (head_dim * math.log(scaling.original_max_positions
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(dim_at(scaling.beta_fast)), 0)
+    hi = min(math.ceil(dim_at(scaling.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    inv = rope_freqs(head_dim, theta)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
+
+
 def rope_cos_sin(positions, head_dim: int, theta: float,
-                 sections: tuple[int, ...] | None = None):
+                 sections: tuple[int, ...] | None = None, scaling=None):
     """cos/sin tables.
 
     positions: (B, S) int32, or (3, B, S) for M-RoPE where the three planes
     are temporal / height / width position ids. With M-RoPE, frequency slots
     are split into ``sections`` groups (sizes in half-dim units), each group
-    indexed by its own plane — the qwen2-vl scheme.
+    indexed by its own plane — the qwen2-vl scheme. ``scaling`` (a
+    ``config.YarnScaling``) takes YaRN's frequencies and multiplies both
+    tables by its ``attention_factor``.
     """
-    inv = rope_freqs(head_dim, theta)                      # (hd/2,)
+    inv = (rope_freqs(head_dim, theta) if scaling is None
+           else yarn_freqs(head_dim, theta, scaling))      # (hd/2,)
     if positions.ndim == 2:
         ang = positions[..., None].astype(jnp.float32) * inv   # (B,S,hd/2)
     else:
@@ -75,6 +101,9 @@ def rope_cos_sin(positions, head_dim: int, theta: float,
             parts.append(ang_all[i, :, :, start:start + sec])
             start += sec
         ang = jnp.concatenate(parts, axis=-1)              # (B,S,hd/2)
+    if scaling is not None:
+        a = scaling.attention_factor
+        return jnp.cos(ang) * a, jnp.sin(ang) * a
     return jnp.cos(ang), jnp.sin(ang)
 
 

@@ -6,20 +6,33 @@ state; tokens are dynamically partitioned (`Part`), computed on the owning
 shard (`Gather` + matmul), and stitched back (`Stitch`) — §4.2's
 Part/Gather/Stitch pipeline, realized as shard_map + all_to_all / psum.
 
-Strategies (auto-chosen from num_experts vs the mesh "model" size):
-  EP  (experts >= model-axis, e.g. qwen3-moe's 128): experts sharded over
-      "model".
-      - big token counts (train/prefill): tokens additionally split over
-        "model" on the sequence dim; dispatch rows travel via all_to_all.
-      - small token counts (decode): tokens replicated over "model"; each
-        shard computes its own experts' rows and the outputs are stitched
-        with a psum.
-  TP  (experts < model-axis, e.g. grok-1's 8): every device holds all experts
-      but a 1/tp slice of d_ff; dispatch is local, the combine psums partial
-      d_ff contributions (Megatron-style).
+Which experts a model holds: the router always scores all ``num_experts``
+(E), while the expert leaves hold experts [0, ``experts_held``) — all of
+them by default, or one chip's share of expert parallelism, where the
+absent experts' part of the result is left out (no stand-in for their
+chips or their exchange).
 
-Both use fixed expert capacity with drop + zero-fill (the standard TPU MoE
-formulation) and return an auxiliary load-balancing loss.
+Serving (every mode but training) runs the dropless held layer
+(``_moe_held``): the top-k assignments that fall on the experts a shard
+holds are sorted by expert and go through ``kernels.ops.grouped_matmul``
+with the held experts' row counts as group sizes, then are weighted by
+the renormalised router weights and summed back to their tokens. A
+token's output depends on its own routing only, never on its batch mates.
+Layouts (auto-chosen from the held experts vs the mesh "model" size):
+  EP  (held >= model axis, e.g. qwen3-moe's 128): experts sharded over
+      "model", tokens replicated; shard i holds experts [i·H_l, (i+1)·H_l)
+      and the outputs are stitched with a psum.
+  TP  (held < model axis, e.g. grok-1's 8; and ``f2d``): every shard holds
+      all experts but a slice of d_ff; the psum adds the partial outputs.
+The held layer also counts, per call, the rows it computed and the routed
+assignments of the valid tokens (tokens × k): the serving step sums both
+over its layers.
+
+Training keeps the standard TPU formulation: fixed expert capacity with
+drop + zero-fill and an auxiliary load-balancing loss, tokens split over
+"model" on the sequence dim with all_to_all dispatch where the sequence
+divides (EP), or all experts local with d_ff sharded (TP). Elsewhere in
+training (EP with an undivided sequence) the held layer runs, with the loss.
 """
 
 from __future__ import annotations
@@ -31,6 +44,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
+from repro.kernels import ops
 from repro.models import modules as m
 from repro.spmd.sharding import dp_axes
 
@@ -39,16 +53,16 @@ _ACT = {"silu": jax.nn.silu, "gelu": lambda x: jax.nn.gelu(x, approximate=True)}
 
 def init_moe(cfg: ModelConfig, key):
     mo = cfg.moe
-    d, E, f = cfg.d_model, mo.num_experts, mo.d_ff_expert
+    d, E, H, f = cfg.d_model, mo.num_experts, mo.held, mo.d_ff_expert
     ks = m.split_keys(key, 4)
     return m.merge(
         m.named("router", m.dense_init(ks[0], (d, E), ("embed", None))),
         m.named("w_gate", m.dense_init(
-            ks[1], (E, d, f), ("experts", "expert_embed", "expert_ff"))),
+            ks[1], (H, d, f), ("experts", "expert_embed", "expert_ff"))),
         m.named("w_in", m.dense_init(
-            ks[2], (E, d, f), ("experts", "expert_embed", "expert_ff"))),
+            ks[2], (H, d, f), ("experts", "expert_embed", "expert_ff"))),
         m.named("w_out", m.dense_init(
-            ks[3], (E, f, d), ("experts", "expert_ff", "expert_embed"))),
+            ks[3], (H, f, d), ("experts", "expert_ff", "expert_embed"))),
     )
 
 
@@ -87,16 +101,16 @@ def _expert_ffn(disp, w_gate, w_in, w_out, act):
 
 
 def _moe_local(x, params, cfg: ModelConfig, *, mode: str, axis: str):
-    """Per-shard MoE body (inside shard_map). x: (T_l, d).
+    """Per-shard capacity MoE body of training (inside shard_map).
+    x: (T_l, d).
 
-    mode: "ep_a2a" | "ep_psum" | "tp".
+    mode: "ep_a2a" | "tp".
     """
     mo = cfg.moe
     E, k = mo.num_experts, mo.experts_per_token
     T, d = x.shape
     C = max(8, int(math.ceil(T * k / E * mo.capacity_factor)))
-    act = _ACT["gelu" if cfg.mlp_activation == "gelu_mlp"
-               else cfg.mlp_activation]
+    act = _act(cfg)
 
     w, idx, probs = _route(x, params["router"].astype(x.dtype), k)
     aux = _aux_loss(probs, idx, E)
@@ -106,26 +120,6 @@ def _moe_local(x, params, cfg: ModelConfig, *, mode: str, axis: str):
     wg = params["w_gate"].astype(x.dtype)
     wi = params["w_in"].astype(x.dtype)
     wo = params["w_out"].astype(x.dtype)
-
-    if mode == "ep_psum":
-        # experts sharded; tokens replicated over `axis`: each shard builds
-        # dispatch rows for its local experts only, outputs stitched by psum.
-        tp = jax.lax.axis_size(axis)
-        E_l = E // tp
-        e0 = jax.lax.axis_index(axis) * E_l
-        local = (idx >= e0) & (idx < e0 + E_l) & keep
-        lidx = jnp.where(local, idx - e0, 0)
-        lpos = jnp.minimum(pos, C - 1)
-        xk = jnp.broadcast_to(x[:, None, :], (T, k, d)).reshape(T * k, d)
-        contrib = jnp.where(local.reshape(-1, 1), xk, 0)
-        disp = jnp.zeros((E_l, C, d), x.dtype).at[
-            lidx.reshape(-1), lpos.reshape(-1)].add(contrib)
-        comb = _expert_ffn(disp, wg, wi, wo, act)
-        got = comb[lidx.reshape(-1), lpos.reshape(-1)].reshape(T, k, d)
-        wk = jnp.where(local, w, 0.0).astype(x.dtype)
-        y = jnp.einsum("tkd,tk->td", got, wk)
-        y = jax.lax.psum(y, axis)
-        return y, aux
 
     # common dispatch build over all E buckets
     lpos = jnp.minimum(pos, C - 1)
@@ -154,8 +148,59 @@ def _moe_local(x, params, cfg: ModelConfig, *, mode: str, axis: str):
     return y, aux
 
 
-def moe_block(params, x, cfg: ModelConfig, f2d: bool = False):
-    """x: (B, S, d) global -> (y, aux_loss scalar). shard_map wrapper.
+def _act(cfg: ModelConfig):
+    return _ACT["gelu" if cfg.mlp_activation == "gelu_mlp"
+                else cfg.mlp_activation]
+
+
+def _moe_held(x, valid, params, cfg: ModelConfig, *, e_axis):
+    """The dropless expert layer over the experts this shard holds (inside
+    shard_map). x: (T, d); valid: (T,) bool, the tokens to compute.
+
+    The shard holds experts [e0, e0 + H) of the E the router scores, H the
+    expert leaves' count, e0 = the ``e_axis`` index × H (0 without one).
+    Of the T·k assignments (the static row bound), those of valid tokens to
+    held experts are sorted by expert and multiplied by their expert's
+    matrices in one grouped matmul each (gate, in, out); the others are
+    never multiplied. Returns (y (T, d), aux loss, counts int32 (2,): rows
+    computed here, assignments of the valid tokens)."""
+    mo = cfg.moe
+    E, k = mo.num_experts, mo.experts_per_token
+    T, d = x.shape
+    H = params["w_gate"].shape[0]
+    e0 = jax.lax.axis_index(e_axis) * H if e_axis else 0
+
+    w, idx, probs = _route(x, params["router"].astype(x.dtype), k)
+    aux = _aux_loss(probs, idx, E)
+    here = (idx >= e0) & (idx < e0 + H) & valid[:, None]
+    group = jnp.where(here, idx - e0, H).reshape(-1)    # H: not computed
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((H,), jnp.int32).at[group].add(1, mode="drop")
+    rows = jnp.take(x, order // k, axis=0)             # (T·k, d), by expert
+
+    def gmm(lhs, name):
+        return ops.grouped_matmul(lhs, params[name].astype(x.dtype), sizes)
+
+    h = _act(cfg)(gmm(rows, "w_gate")) * gmm(rows, "w_in")
+    out = gmm(h.astype(x.dtype), "w_out")               # (T·k, d) float32
+    n = jnp.sum(sizes)
+    out = jnp.where((jnp.arange(T * k) < n)[:, None], out, 0.0)
+    # back to (token, slot) order, each token's k rows summed in slot order
+    got = jnp.take(out, jnp.argsort(order), axis=0).reshape(T, k, d)
+    y = jnp.einsum("tkd,tk->td", got, jnp.where(here, w, 0.0))
+    counts = jnp.stack([n, jnp.sum(valid.astype(jnp.int32)) * k])
+    return y.astype(x.dtype), aux, counts
+
+
+def moe_block(params, x, cfg: ModelConfig, f2d: bool = False, *,
+              train: bool = True, valid=None):
+    """x: (B, S, d) global -> (y, second). shard_map wrapper.
+
+    ``train``: the capacity layer, or the held layer where EP cannot split
+    the sequence; second = the load-balancing loss (f32 scalar). Otherwise
+    (serving) the dropless held layer, and second = int32 (2,) counts: rows
+    computed on held experts and routed assignments (tokens × k), of the
+    tokens ``valid`` (B, S) marks (all, when None).
 
     f2d: serving layout for small-E models (grok-1) — expert d_ff sharded
     over BOTH mesh axes, tokens replicated; the partial outputs psum over
@@ -163,31 +208,25 @@ def moe_block(params, x, cfg: ModelConfig, f2d: bool = False):
     psums — the right trade when tokens-per-step is small (decode).
     """
     mesh = jax.sharding.get_abstract_mesh()
-    mo = cfg.moe
+    H = cfg.moe.held
     tp = mesh.shape.get("model", 1)
-    ep = (not f2d) and mo.num_experts >= tp \
-        and mo.num_experts % max(tp, 1) == 0
+    ep = not f2d and tp > 1 and H % tp == 0
     B, S, d = x.shape
     dp = dp_axes(mesh)
     dp_sz = math.prod(mesh.shape[a] for a in dp) if dp else 1
     dpb = dp if (dp and B % dp_sz == 0) else ()
     dps = (dpb if len(dpb) > 1 else (dpb[0] if dpb else None))
-    seq_split = ep and tp > 1 and S % tp == 0
 
-    if not ep:
-        mode = "tp"
-    elif seq_split:
-        mode = "ep_a2a"
-    elif tp > 1:
-        mode = "ep_psum"
+    if not train or (ep and S % tp):
+        mode = "held"
     else:
-        mode = "tp"   # single model shard: all experts local, psum trivial
+        mode = "ep_a2a" if ep else "tp"
 
     f_axes = (tuple(dp) + ("model",)) if f2d else ("model",)
+    f_axis = f_axes if len(f_axes) > 1 else f_axes[0]
     seq_ax = "model" if mode == "ep_a2a" else None
-    e_ax = "model" if mode in ("ep_a2a", "ep_psum") else None
-    f_ax = (f_axes if len(f_axes) > 1 else f_axes[0]) \
-        if mode == "tp" else None
+    e_ax = "model" if ep else None
+    f_ax = None if ep else f_axis
     x_dps = None if f2d else dps
     wspec = {
         "router": P(None, None),
@@ -195,21 +234,34 @@ def moe_block(params, x, cfg: ModelConfig, f2d: bool = False):
         "w_in": P(e_ax, None, f_ax),
         "w_out": P(e_ax, f_ax, None),
     }
+    if valid is None:
+        valid = jnp.ones((B, S), bool)
 
-    def body(params, x):
+    def body(params, x, valid):
         b, s, _ = x.shape
-        y, aux = _moe_local(x.reshape(b * s, d), params, cfg,
-                            mode=mode,
-                            axis=f_axes if f2d else "model")
+        xt = x.reshape(b * s, d)
+        if mode == "held":
+            y, aux, counts = _moe_held(xt, valid.reshape(b * s), params, cfg,
+                                       e_axis=e_ax)
+            y = jax.lax.psum(y, "model" if ep else f_axis)
+            if ep:      # each shard computed its own experts' rows
+                counts = counts.at[0].set(jax.lax.psum(counts[0], "model"))
+        else:
+            y, aux = _moe_local(xt, params, cfg, mode=mode, axis=f_axis)
         if dp and not f2d:
             aux = jax.lax.pmean(aux, dp)
-        if mode != "ep_psum" and not f2d:
+            if mode == "held":
+                counts = jax.lax.psum(counts, dp)
+        if mode != "held" and not f2d:
             aux = jax.lax.pmean(aux, "model")
-        return y.reshape(b, s, d), aux
+        return y.reshape(b, s, d), (aux if train else counts)
 
-    y, aux = jax.shard_map(
+    x_spec = P(x_dps, seq_ax, None)
+    y, second = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(wspec, P(x_dps, seq_ax, None)),
-        out_specs=(P(x_dps, seq_ax, None), P()),
-    )(params, x)
-    return y, aux
+        in_specs=(wspec, x_spec, P(x_dps, seq_ax)),
+        out_specs=(x_spec, P()),
+        # the grouped matmul's pallas_call outputs carry no varying-axes type
+        check_vma=mode != "held",
+    )(params, x, valid)
+    return y, second

@@ -125,8 +125,8 @@ def _attn_full(bp, x, cfg: ModelConfig, ctx, kind: str):
     """Full-sequence self attention (train / prefill). Returns (y, cache)."""
     window = cfg.sliding_window if kind == "local" else None
     h = apply_norm(bp["norm"], x, cfg)
-    q = project_q(bp["attn"], h, cfg, ctx["cos_sin"])
-    k, v = project_kv(bp["attn"], h, cfg, ctx["cos_sin"])
+    q = project_q(bp["attn"], h, cfg, _rope(ctx, kind))
+    k, v = project_kv(bp["attn"], h, cfg, _rope(ctx, kind))
     y = sharded_attention(
         q, k, v, cfg, causal=True, window=window,
         cap=cfg.attn_logit_softcap, scale=attention_scale(cfg),
@@ -138,12 +138,16 @@ def _attn_full(bp, x, cfg: ModelConfig, ctx, kind: str):
     return x, {"k": k, "v": v}
 
 
-def _mlp_part(bp, x, cfg: ModelConfig, ctx=None):
+def _mlp_part(bp, x, cfg: ModelConfig, ctx=None, mode: str = "train"):
+    """Returns (x, aux): an expert layer's load-balancing loss in training,
+    its int32 (2,) counts when serving (``moe.moe_block``); zero else."""
     h = apply_norm(bp["norm2"], x, cfg)
     aux = jnp.zeros((), jnp.float32)
     if cfg.moe is not None and "moe" in bp:
         y, aux = moe_mod.moe_block(bp["moe"], h, cfg,
-                                   f2d=bool(ctx and ctx.get("moe_f2d")))
+                                   f2d=bool(ctx and ctx.get("moe_f2d")),
+                                   train=mode == "train",
+                                   valid=(ctx or {}).get("valid"))
     else:
         y = apply_mlp(bp["mlp"], h, cfg)
     if cfg.post_block_norm:
@@ -154,8 +158,8 @@ def _mlp_part(bp, x, cfg: ModelConfig, ctx=None):
 def _attn_decode(bp, x, cfg: ModelConfig, ctx, cache, kind: str):
     window = cfg.sliding_window if kind == "local" else None
     h = apply_norm(bp["norm"], x, cfg)
-    q = project_q(bp["attn"], h, cfg, ctx["cos_sin"])
-    k, v = project_kv(bp["attn"], h, cfg, ctx["cos_sin"])
+    q = project_q(bp["attn"], h, cfg, _rope(ctx, kind))
+    k, v = project_kv(bp["attn"], h, cfg, _rope(ctx, kind))
     kc = update_cache(cache["k"], k, ctx["pos"])
     vc = update_cache(cache["v"], v, ctx["pos"])
     y = decode_attention(q, kc, vc, ctx["pos"], window=window,
@@ -175,8 +179,8 @@ def _attn_decode_paged(bp, x, cfg: ModelConfig, ctx, cache, kind: str):
     dequant is fused into the attention kernel."""
     window = cfg.sliding_window if kind == "local" else None
     h = apply_norm(bp["norm"], x, cfg)
-    q = project_q(bp["attn"], h, cfg, ctx["cos_sin"])
-    k, v = project_kv(bp["attn"], h, cfg, ctx["cos_sin"])
+    q = project_q(bp["attn"], h, cfg, _rope(ctx, kind))
+    k, v = project_kv(bp["attn"], h, cfg, _rope(ctx, kind))
     if "k_scale" in cache:
         kvd = quant.kv_dtype_name(cache["k"].dtype)
         k, ksr = quant.quantize_kv(k, kvd)
@@ -209,8 +213,8 @@ def _attn_chunk_paged(bp, x, cfg: ModelConfig, ctx, cache, kind: str):
     cache: {"k","v"} page pools (num_blocks, K, block_size, hd)."""
     window = cfg.sliding_window if kind == "local" else None
     h = apply_norm(bp["norm"], x, cfg)
-    q = project_q(bp["attn"], h, cfg, ctx["cos_sin"])
-    k, v = project_kv(bp["attn"], h, cfg, ctx["cos_sin"])
+    q = project_q(bp["attn"], h, cfg, _rope(ctx, kind))
+    k, v = project_kv(bp["attn"], h, cfg, _rope(ctx, kind))
     if "k_scale" in cache:
         kvd = quant.kv_dtype_name(cache["k"].dtype)
         k, ksr = quant.quantize_kv(k, kvd)
@@ -247,8 +251,8 @@ def _attn_ragged_paged(bp, x, cfg: ModelConfig, ctx, cache, kind: str):
     path; row-wise projections/MLP are shared across the pack."""
     window = cfg.sliding_window if kind == "local" else None
     h = apply_norm(bp["norm"], x, cfg)
-    q = project_q(bp["attn"], h, cfg, ctx["cos_sin"])
-    k, v = project_kv(bp["attn"], h, cfg, ctx["cos_sin"])
+    q = project_q(bp["attn"], h, cfg, _rope(ctx, kind))
+    k, v = project_kv(bp["attn"], h, cfg, _rope(ctx, kind))
     if "k_scale" in cache:
         y, kc, vc, ksc, vsc = ragged_chunk_update_attend(
             q, k, v, cache["k"], cache["v"], ctx["block_tables"],
@@ -292,22 +296,22 @@ def _block_apply(kind, bp, x, cfg, ctx, mode, cache=None):
         return x + y, (st if mode == "prefill" else None), zero
     if mode == "ragged_paged":
         x, c = _attn_ragged_paged(bp, x, cfg, ctx, cache, kind)
-        x, aux = _mlp_part(bp, x, cfg, ctx)
+        x, aux = _mlp_part(bp, x, cfg, ctx, mode)
         return x, c, aux
     if mode == "chunk_paged":
         x, c = _attn_chunk_paged(bp, x, cfg, ctx, cache, kind)
-        x, aux = _mlp_part(bp, x, cfg, ctx)
+        x, aux = _mlp_part(bp, x, cfg, ctx, mode)
         return x, c, aux
     if mode == "decode_paged":
         x, c = _attn_decode_paged(bp, x, cfg, ctx, cache, kind)
-        x, aux = _mlp_part(bp, x, cfg, ctx)
+        x, aux = _mlp_part(bp, x, cfg, ctx, mode)
         return x, c, aux
     if mode == "decode":
         x, c = _attn_decode(bp, x, cfg, ctx, cache, kind)
-        x, aux = _mlp_part(bp, x, cfg, ctx)
+        x, aux = _mlp_part(bp, x, cfg, ctx, mode)
         return x, c, aux
     x, c = _attn_full(bp, x, cfg, ctx, kind)
-    x, aux = _mlp_part(bp, x, cfg, ctx)
+    x, aux = _mlp_part(bp, x, cfg, ctx, mode)
     return x, (c if mode == "prefill" else None), aux
 
 
@@ -316,9 +320,32 @@ def _block_apply(kind, bp, x, cfg, ctx, mode, cache=None):
 # ---------------------------------------------------------------------------
 
 
+def _rope_tables(cfg: ModelConfig, positions):
+    """The cos/sin tables of the layers: one (cos, sin) pair for every
+    kind where the config has no rope scaling (so every dense program
+    builds the one table it always has), else {kind: pair}, with the
+    scaling on the full-attention ("attn") layers and the plain table on
+    the rest, as the published per-kind rope parameters have it. None
+    for a model without attention heads."""
+    if not cfg.num_heads:
+        return None
+    plain = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
+                         cfg.rope_sections)
+    if cfg.rope_scaling is None:
+        return plain
+    return {"attn": rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
+                                 cfg.rope_sections, cfg.rope_scaling),
+            "local": plain}
+
+
+def _rope(ctx, kind: str):
+    """The table that layers of this kind rotate with."""
+    cs = ctx["cos_sin"]
+    return cs[kind] if isinstance(cs, dict) else cs
+
+
 def _make_ctx(cfg: ModelConfig, positions, pcfg: ParallelConfig = None):
-    cos_sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
-                           cfg.rope_sections) if cfg.num_heads else None
+    cos_sin = _rope_tables(cfg, positions)
     pos = positions if positions.ndim == 1 else None
     return {"cos_sin": cos_sin, "pos": pos,
             "moe_f2d": bool(pcfg and pcfg.expert_ff_2d)}
@@ -342,6 +369,9 @@ def _scan_periods(params, x, cfg: ModelConfig, ctx, mode: str,
     mode="train":    xs=blocks,         carry=(x, aux), ys=None
     mode="prefill":  xs=blocks,         carry=(x, aux), ys=cache slices
     mode="decode":   xs=(blocks,cache), carry=(x, aux), ys=new cache slices
+
+    aux: the experts' load-balancing loss in training; in every serving
+    mode of an expert model, the counts of ``moe.moe_block``.
     """
     kinds, NP = period_structure(cfg)
 
@@ -404,8 +434,18 @@ def _scan_periods(params, x, cfg: ModelConfig, ctx, mode: str,
     xs = ((params["blocks"], cache)
           if mode in ("decode", "decode_paged", "chunk_paged", "ragged_paged")
           else params["blocks"])
-    (x, aux), caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    # an expert model's serving modes carry its layers' summed counts
+    aux0 = (jnp.zeros((2,), jnp.int32) if cfg.moe is not None
+            and mode != "train" else jnp.zeros((), jnp.float32))
+    (x, aux), caches = jax.lax.scan(body, (x, aux0), xs)
     return x, aux, caches
+
+
+def _counts(cfg: ModelConfig, moe):
+    """A serving step's expert counts (int32 (2,): rows computed on held
+    experts, routed assignments, summed over layers), or None for a model
+    without experts."""
+    return moe if cfg.moe is not None else None
 
 
 def forward_loss(params, batch, cfg: ModelConfig, pcfg: ParallelConfig,
@@ -479,7 +519,7 @@ def prefill_chunk_paged(params, cache, batch, cfg: ModelConfig,
     (B,) valid columns, block_tables (B, nb), ctx_lens (B,) visible tokens
     including this chunk (= q_start + q_lens).
     Returns (logits (B, V_pad) fp32 at each row's last valid token,
-    new_cache). The engine samples from the logits only when the chunk
+    new_cache, expert counts (``_counts``) of the valid columns). The engine samples from the logits only when the chunk
     completes its prompt. With ``all_logits=True`` the logits cover every
     chunk position — (B, C, V_pad) — which is what the speculative verify
     step needs: one widened pass scoring all K+1 candidate positions.
@@ -489,24 +529,24 @@ def prefill_chunk_paged(params, cache, batch, cfg: ModelConfig,
     assert cfg.rope_sections is None, "chunked prefill: no M-RoPE frontends"
     x = embed(params["embed"]["table"], tokens, cfg)
     positions = batch["q_start"][:, None] + jnp.arange(C, dtype=jnp.int32)
-    cos_sin = (rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
-                            cfg.rope_sections) if cfg.num_heads else None)
-    ctx = {"cos_sin": cos_sin, "pos": None,
+    ctx = {"cos_sin": _rope_tables(cfg, positions), "pos": None,
            "q_start": batch["q_start"], "q_lens": batch["q_lens"],
            "block_tables": batch["block_tables"],
            "ctx_lens": batch["ctx_lens"],
            "moe_f2d": bool(pcfg and pcfg.expert_ff_2d)}
-    x, _, new_cache = _scan_periods(params, x, cfg, ctx, "chunk_paged",
-                                    ParallelConfig(remat="none"), cache)
+    if cfg.moe is not None:
+        ctx["valid"] = jnp.arange(C)[None, :] < batch["q_lens"][:, None]
+    x, moe, new_cache = _scan_periods(params, x, cfg, ctx, "chunk_paged",
+                                      ParallelConfig(remat="none"), cache)
     x = apply_norm(params["final_norm"], x, cfg)
     ht = head_table(params["embed"], cfg)
     if all_logits:
         logits = decode_logits(x.reshape(B * C, 1, -1), ht, cfg)
-        return logits.reshape(B, C, -1), new_cache
+        return logits.reshape(B, C, -1), new_cache, _counts(cfg, moe)
     last = jnp.clip(batch["q_lens"] - 1, 0, C - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)   # (B,1,d)
     logits = decode_logits(x_last, ht, cfg)
-    return logits, new_cache
+    return logits, new_cache, _counts(cfg, moe)
 
 
 def prefill_chunk_ragged(params, cache, batch, cfg: ModelConfig,
@@ -520,7 +560,7 @@ def prefill_chunk_ragged(params, cache, batch, cfg: ModelConfig,
     slot), row_seq (T,) each row's owning pack slot, block_tables (S, nb),
     ctx_lens (S,) visible tokens including each chunk.
     Returns (logits (S, V_pad) fp32 at each sequence's last packed row,
-    new_cache). Row-wise work (embedding, norms, projections, MLP) runs
+    new_cache, expert counts of the packed rows). Row-wise work (embedding, norms, projections, MLP) runs
     once over the flat batch; only the attention is per-sequence. S == 1
     is the single-chunk path in a different layout — the engine keeps
     outputs byte-identical across the two (tests pin it).
@@ -531,22 +571,24 @@ def prefill_chunk_ragged(params, cache, batch, cfg: ModelConfig,
     assert cfg.ssm is None and not cfg.shared_attn_period, \
         "packed prefill is attention-only (see supports_packed_prefill)"
     x = embed(params["embed"]["table"], tokens, cfg)
-    cos_sin = (rope_cos_sin(batch["positions"], cfg.head_dim, cfg.rope_theta,
-                            cfg.rope_sections) if cfg.num_heads else None)
-    ctx = {"cos_sin": cos_sin, "pos": None,
+    ctx = {"cos_sin": _rope_tables(cfg, batch["positions"]), "pos": None,
            "starts": batch["starts"], "ends": batch["ends"],
            "row_seq": batch["row_seq"],
            "block_tables": batch["block_tables"],
            "ctx_lens": batch["ctx_lens"],
            "moe_f2d": bool(pcfg and pcfg.expert_ff_2d)}
-    x, _, new_cache = _scan_periods(params, x, cfg, ctx, "ragged_paged",
-                                    ParallelConfig(remat="none"), cache)
+    if cfg.moe is not None:
+        row, seq = jnp.arange(T), batch["row_seq"]
+        ctx["valid"] = ((row >= batch["starts"][seq])
+                        & (row < batch["ends"][seq]))[None]
+    x, moe, new_cache = _scan_periods(params, x, cfg, ctx, "ragged_paged",
+                                      ParallelConfig(remat="none"), cache)
     x = apply_norm(params["final_norm"], x, cfg)
     ht = head_table(params["embed"], cfg)
     last = jnp.clip(batch["ends"] - 1, 0, T - 1)                   # (S,)
     x_last = jnp.take(x[0], last, axis=0)[:, None]                 # (S,1,d)
     logits = decode_logits(x_last, ht, cfg)
-    return logits, new_cache
+    return logits, new_cache, _counts(cfg, moe)
 
 
 def decode_step_paged(params, cache, batch, cfg: ModelConfig,
@@ -556,7 +598,8 @@ def decode_step_paged(params, cache, batch, cfg: ModelConfig,
     batch: token (B,1), pos (B,) write position, block_tables (B, nb),
     ctx_lens (B,) — visible tokens incl. this one; 0 masks an idle slot.
     cache: pytree of {"k","v"} page pools with leading layer-stack dim.
-    Returns (logits (B, V_pad) fp32, new_cache).
+    Returns (logits (B, V_pad) fp32, new_cache, expert counts of the
+    active slots).
     """
     token, pos = batch["token"], batch["pos"]
     B = token.shape[0]
@@ -565,17 +608,17 @@ def decode_step_paged(params, cache, batch, cfg: ModelConfig,
         positions = jnp.broadcast_to(pos[None, :, None], (3, B, 1))
     else:
         positions = pos[:, None]
-    cos_sin = (rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
-                            cfg.rope_sections) if cfg.num_heads else None)
-    ctx = {"cos_sin": cos_sin, "pos": pos,
+    ctx = {"cos_sin": _rope_tables(cfg, positions), "pos": pos,
            "block_tables": batch["block_tables"],
            "ctx_lens": batch["ctx_lens"],
            "moe_f2d": bool(pcfg and pcfg.expert_ff_2d)}
-    x, _, new_cache = _scan_periods(params, x, cfg, ctx, "decode_paged",
-                                    ParallelConfig(remat="none"), cache)
+    if cfg.moe is not None:
+        ctx["valid"] = (batch["ctx_lens"] > 0)[:, None]
+    x, moe, new_cache = _scan_periods(params, x, cfg, ctx, "decode_paged",
+                                      ParallelConfig(remat="none"), cache)
     x = apply_norm(params["final_norm"], x, cfg)
     logits = decode_logits(x, head_table(params["embed"], cfg), cfg)
-    return logits, new_cache
+    return logits, new_cache, _counts(cfg, moe)
 
 
 def decode_step(params, cache, batch, cfg: ModelConfig,
@@ -589,9 +632,7 @@ def decode_step(params, cache, batch, cfg: ModelConfig,
         positions = jnp.broadcast_to(pos[None, :, None], (3, B, 1))
     else:
         positions = pos[:, None]
-    cos_sin = (rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
-                            cfg.rope_sections) if cfg.num_heads else None)
-    ctx = {"cos_sin": cos_sin, "pos": pos,
+    ctx = {"cos_sin": _rope_tables(cfg, positions), "pos": pos,
            "moe_f2d": bool(pcfg and pcfg.expert_ff_2d)}
     x, _, new_cache = _scan_periods(params, x, cfg, ctx, "decode",
                                     ParallelConfig(remat="none"), cache)

@@ -346,6 +346,10 @@ class InferenceEngine:
                           num_host_blocks * self._dev_block_bytes
                           / 2 ** 20, 3),
                       "queue_wait_s": 0.0, "first_admits": 0}
+        if self.runner.counts_experts:
+            # Σ over steps of the rows computed on held experts and of the
+            # routed assignments (tokens × k), all layers
+            self.stats.update(moe_rows=0, moe_assignments=0)
         self.step_count = 0           # virtual clock: one step() = one tick
         self.latency_record_cap = latency_record_cap
         # retirement-time latency aggregation: bounded state the metrics
@@ -800,7 +804,9 @@ class InferenceEngine:
         taken with ``jax.profiler.start_trace`` puts the host's work on the
         same clock as the device's operations: ``serve.step`` (the whole
         call; stats ``step``, ``rows``, ``decode_pages`` (KV pages the
-        decode rows attend), ``chunk_tokens``, ``full``) holds
+        decode rows attend), ``chunk_tokens``, ``full``, and in an expert
+        model ``moe_rows`` and ``moe_assign``: rows computed on held
+        experts and routed assignments, summed over layers) holds
         ``serve.schedule`` (with ``serve.admit`` per admission),
         ``serve.copies`` (swaps, encodes, copy-on-write), ``serve.build``
         (host arrays, ``serve.h2d`` their transfer), ``serve.dispatch``,
@@ -906,6 +912,14 @@ class InferenceEngine:
                 if d2h_token is not None:
                     self._drain_swap_out(d2h_token)
                 nxt = jax.tree.map(np.asarray, nxt)
+            moe = {}
+            if self.runner.counts_experts:
+                nxt, counts = nxt
+                moe = {"moe_rows": int(counts[0]),
+                       "moe_assign": int(counts[1])}
+                span.set_metadata(**moe)
+                self.stats["moe_rows"] += moe["moe_rows"]
+                self.stats["moe_assignments"] += moe["moe_assign"]
             with TraceAnnotation("serve.emit"):
                 self._emit(plan, nxt, full, t_step)
             self.stats["steps"] += 1
@@ -922,7 +936,7 @@ class InferenceEngine:
                     cache_hit_tokens=s["cache_hit_tokens"],
                     prefill_tokens=s["prefill_tokens"],
                     queue_wait_s=s["queue_wait_s"],
-                    first_admits=s["first_admits"]))
+                    first_admits=s["first_admits"], **moe))
             return True
 
     def _emit(self, plan: StepPlan, nxt, full: bool, t_step: float) -> None:

@@ -102,6 +102,10 @@ class ModelRunner:
                                       # (speculative decoding lookahead)
     max_logprobs: int = 8             # top-L logprob rows the full path
                                       # returns (engine knob, set at init)
+    counts_experts: bool = False      # step returns ((sampled, int32 (2,)
+                                      # expert counts), cache): rows
+                                      # computed on held experts, routed
+                                      # assignments, summed over layers
 
     def __init__(self, cfg: ModelConfig, pcfg: ParallelConfig):
         self.cfg, self.pcfg = cfg, pcfg
@@ -173,22 +177,29 @@ class TransformerRunner(ModelRunner):
     supports_prefix_caching = True
     supports_packed_prefill = True
 
+    @property
+    def counts_experts(self) -> bool:
+        return self.cfg.moe is not None
+
     def step(self, params, cache, a, *, has_chunk, full_sampling=False):
+        moe_c = None
         if has_chunk:
             if "c_starts" in a:
-                logits_c, cache = transformer.prefill_chunk_ragged(
+                logits_c, cache, moe_c = transformer.prefill_chunk_ragged(
                     params, cache, self._ragged_batch(a), self.cfg,
                     self.pcfg)
             else:
-                logits_c, cache = transformer.prefill_chunk_paged(
+                logits_c, cache, moe_c = transformer.prefill_chunk_paged(
                     params, cache, self._chunk_batch(a), self.cfg,
                     self.pcfg)
         else:
             logits_c = None
-        logits_d, cache = transformer.decode_step_paged(
+        logits_d, cache, moe = transformer.decode_step_paged(
             params, cache, self._decode_batch(a), self.cfg, self.pcfg)
-        return self._sample(logits_d, logits_c, a, has_chunk,
-                            full_sampling), cache
+        out = self._sample(logits_d, logits_c, a, has_chunk, full_sampling)
+        if not self.counts_experts:
+            return out, cache
+        return (out, moe if moe_c is None else moe + moe_c), cache
 
     def init_cache(self, num_blocks, block_size, max_batch,
                    kv_dtype="bf16"):
@@ -239,14 +250,14 @@ class SSMRunner(ModelRunner):
                     chunk_cache[key] = st
                 else:
                     chunk_cache[key] = val
-            logits_c, out = transformer.prefill_chunk_paged(
+            logits_c, out, _ = transformer.prefill_chunk_paged(
                 params, chunk_cache, self._chunk_batch(a), self.cfg,
                 self.pcfg)
             cache = {key: (_scatter_slot(cache[key], out[key], slot)
                            if key in self._state_keys else out[key])
                      for key in cache}
         old_state = {key: cache[key] for key in self._state_keys}
-        logits_d, cache = transformer.decode_step_paged(
+        logits_d, cache, _ = transformer.decode_step_paged(
             params, cache, self._decode_batch(a), self.cfg, self.pcfg)
         for key in self._state_keys:
             cache[key] = _mask_slot_rows(cache[key], old_state[key],
@@ -364,15 +375,15 @@ class SpeculativeRunner(ModelRunner):
                 # packed ragged chunks run through both models (draft KV
                 # must mirror the target's positions exactly)
                 rb = self._ragged_batch(a)
-                logits_c, tgt = transformer.prefill_chunk_ragged(
+                logits_c, tgt, _ = transformer.prefill_chunk_ragged(
                     params["tgt"], tgt, rb, self.cfg, self.pcfg)
-                _, dft = transformer.prefill_chunk_ragged(
+                _, dft, _ = transformer.prefill_chunk_ragged(
                     params["dft"], dft, rb, self.draft_cfg, self.pcfg)
             else:
                 cb = self._chunk_batch(a)
-                logits_c, tgt = transformer.prefill_chunk_paged(
+                logits_c, tgt, _ = transformer.prefill_chunk_paged(
                     params["tgt"], tgt, cb, self.cfg, self.pcfg)
-                _, dft = transformer.prefill_chunk_paged(
+                _, dft, _ = transformer.prefill_chunk_paged(
                     params["dft"], dft, cb, self.draft_cfg, self.pcfg)
         temps, top_ks = a["temps"][:B], a["top_ks"][:B]
         seeds, rids, cnts = a["seeds"][:B], a["rids"][:B], a["counters"][:B]
@@ -391,7 +402,7 @@ class SpeculativeRunner(ModelRunner):
                       "block_tables": a["d_tables"],
                       "ctx_lens": jnp.where(a["d_active"],
                                             a["d_pos"] + i + 1, 0)}
-                lg, dft = transformer.decode_step_paged(
+                lg, dft, _ = transformer.decode_step_paged(
                     params["dft"], dft, db, self.draft_cfg, self.pcfg)
                 if i < k:
                     dlogits.append(lg)
@@ -410,7 +421,7 @@ class SpeculativeRunner(ModelRunner):
               "q_lens": jnp.where(a["d_active"], k + 1, 0),
               "block_tables": a["d_tables"],
               "ctx_lens": jnp.where(a["d_active"], a["d_pos"] + k + 1, 0)}
-        tlogits, tgt = transformer.prefill_chunk_paged(
+        tlogits, tgt, _ = transformer.prefill_chunk_paged(
             params["tgt"], tgt, vb, self.cfg, self.pcfg, all_logits=True)
         draft_logits = (jnp.stack(dlogits, axis=1) if dlogits else
                         jnp.zeros((B, 0, tlogits.shape[-1]),

@@ -125,7 +125,10 @@ class StepRecord:
     read before the step ran: each decode row's context length (the token
     fed included), each prefill chunk's ``(start, n)``, and how many chunks
     end their prompt and so sample a token. The counters are cumulative
-    engine totals after the step."""
+    engine totals after the step. ``moe_rows``/``moe_assign`` are this
+    step's expert counts, summed over layers: rows computed on the held
+    experts and routed assignments (tokens × k); 0 in a model without
+    experts."""
     step: int
     t0: float
     t1: float
@@ -138,6 +141,8 @@ class StepRecord:
     prefill_tokens: int
     queue_wait_s: float        # Σ add → first slot binding, seconds
     first_admits: int          # requests that reached a slot at least once
+    moe_rows: int = 0
+    moe_assign: int = 0
 
     @property
     def chunk(self) -> tuple[int, int] | None:

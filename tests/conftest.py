@@ -15,5 +15,5 @@ def tiny_mesh():
 ALL_ARCHS = [
     "glm4_9b", "starcoder2_3b", "gemma2_27b", "qwen3_32b",
     "whisper_large_v3", "zamba2_2p7b", "qwen2_vl_2b",
-    "qwen3_moe_30b_a3b", "grok1_314b", "mamba2_370m",
+    "qwen3_moe_30b_a3b", "grok1_314b", "mamba2_370m", "mellum2_12b_a2p5b",
 ]

@@ -15,17 +15,13 @@ PCFG = ParallelConfig(remat="none")
 
 
 @pytest.mark.parametrize("arch", ["glm4_9b", "gemma2_27b", "mamba2_370m",
-                                  "zamba2_2p7b", "qwen3_moe_30b_a3b"])
+                                  "zamba2_2p7b", "qwen3_moe_30b_a3b",
+                                  "mellum2_12b_a2p5b"])
 def test_decode_equals_fresh_prefill(arch, tiny_mesh):
     """prefill(S) -> decode(token at S) must produce the same next token as
-    prefill(S+1) on the extended sequence."""
-    import dataclasses
+    prefill(S+1) on the extended sequence. Expert layers serve dropless, at
+    the config's own capacity_factor."""
     cfg = get_config(arch, smoke=True)
-    if cfg.moe is not None:
-        # capacity drops are path-dependent (a dropped prefill token has no
-        # decode analogue); use a no-drop capacity for the equality check
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))
     B, S = 2, 12
     toks = RNG.integers(0, cfg.vocab_size, (B, S + 1)).astype(np.int32)
     with jax.set_mesh(tiny_mesh):

@@ -235,3 +235,49 @@ def test_token_events_carry_their_handoff_time(glm, mesh, tmp_path):
     assert loops and steps
     assert not any(a[0] == b[0] and a[2] < b[3] and b[2] < a[3]
                    for a in loops for b in steps)
+
+
+def test_expert_counts_on_serve_step_and_step_records(glm, mesh, tmp_path):
+    """An expert model's steps report rows computed on the held experts
+    and routed assignments of their own tokens: on ``serve.step``, in the
+    ``StepRecord`` and summed in the stats. With every expert held the
+    two agree; a dense model reports neither."""
+    import dataclasses
+    from repro.models import api
+    cfg = get_config("mellum2_12b_a2p5b", smoke=True)
+    half = dataclasses.replace(
+        cfg, moe=dataclasses.replace(cfg.moe, experts_held=4))
+    k = cfg.moe.experts_per_token
+    seen = {}
+    for name, c in (("all", cfg), ("half", half)):
+        with jax.set_mesh(mesh):
+            params, _ = api.init_model(c, jax.random.key(0))
+            params = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
+        eng = _engine((c, params), mesh)
+        recs = []
+        eng.on_step = recs.append
+        for r in _requests(c):
+            eng.sched.add(r)
+        jax.profiler.start_trace(str(tmp_path / name))
+        try:
+            while eng.sched.has_work:
+                eng.step()
+        finally:
+            jax.profiler.stop_trace()
+        steps = {s[4]["step"]: s[4] for s in _serve_spans(
+            str(tmp_path / name)) if s[1] == "serve.step"}
+        for r in recs:
+            tokens = len(r.decode_ctxs) + sum(n for _, n in r.chunks)
+            assert r.moe_assign == tokens * k * c.num_layers
+            assert 0 <= r.moe_rows <= r.moe_assign
+            assert (steps[r.step]["moe_rows"], steps[r.step]["moe_assign"]) \
+                == (r.moe_rows, r.moe_assign)
+        assert eng.stats["moe_rows"] == sum(r.moe_rows for r in recs)
+        assert eng.stats["moe_assignments"] == sum(r.moe_assign
+                                                   for r in recs)
+        seen[name] = eng.stats
+    assert seen["all"]["moe_rows"] == seen["all"]["moe_assignments"]
+    assert 0 < seen["half"]["moe_rows"] < seen["half"]["moe_assignments"]
+    dense = _engine(glm, mesh)
+    assert "moe_rows" not in dense.stats
+    assert not dense.runner.counts_experts

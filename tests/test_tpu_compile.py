@@ -1,5 +1,5 @@
 """Compile the serving path's Pallas kernels for a TPU v5e at starcoder2_3b's
-published widths, without a chip.
+published widths (the grouped matmul at Mellum2's), without a chip.
 
 Interpret mode (every other kernel test) never applies Mosaic's tiling rules;
 this file does: each kernel is lowered and compiled for a described v5e and
@@ -129,11 +129,11 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _roofline_pattern():
-    """``PATTERN`` of the benchmark's ``paged_decode_roofline`` metric, read
-    from its file (importing it would need the harness's package)."""
+def _roofline_pattern(metric="paged_decode_roofline"):
+    """``PATTERN`` of one of the benchmark's roofline metrics, read from its
+    file (importing it would need the harness's package)."""
     path = (pathlib.Path(__file__).parents[1] / "benchmarks" / "chip" /
-            "metrics" / "paged_decode_roofline.py")
+            "metrics" / f"{metric}.py")
     for node in ast.parse(path.read_text()).body:
         if isinstance(node, ast.Assign) and \
                 getattr(node.targets[0], "id", None) == "PATTERN":
@@ -152,7 +152,6 @@ def test_decode_kernel_keeps_the_roofline_signature(cell, one_chip):
     """The compiled decode call at a cell's shapes is the custom call the
     benchmark's roofline metric matches in the device trace, whose event
     names print the operands with their shapes."""
-    from jax._src.lib import _jax
     b, h = CELL_DECODES[cell]
     nb, n = 256, 256 * b + 1
     shapes = [((b, h, HD), jnp.bfloat16), ((n, K, BS, HD), jnp.bfloat16),
@@ -160,11 +159,42 @@ def test_decode_kernel_keeps_the_roofline_signature(cell, one_chip):
               ((b,), jnp.int32)]
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(pa.paged_attention).lower(*args).compile()
+    calls = _custom_calls(compiled)
+    assert len(calls) == 1
+    assert re.search(_roofline_pattern(), calls[0]), calls[0][:300]
+
+
+def _custom_calls(compiled):
+    """The compiled program's kernel calls as the device trace names them,
+    operands printed with their shapes."""
+    from jax._src.lib import _jax
     opts = _jax.HloPrintOptions()
     opts.print_operand_shape = True
     text = "\n".join(m.to_string(opts)
                      for m in compiled.runtime_executable().hlo_modules())
-    calls = [line for line in text.splitlines()
-             if "tpu_custom_call" in line]
+    return [line for line in text.splitlines() if "tpu_custom_call" in line]
+
+
+# mellum2_12b-ep4.ide_completion's grouped matmuls: 16 held experts of
+# 2304 x 896, 8 routed per token; a decode step's 16 rows, a 256-token chunk
+GMM_SHAPES = {"decode_gate": (16 * 8, 2304, 896),
+              "chunk_out": (256 * 8, 896, 2304)}
+
+
+@pytest.mark.parametrize("name", sorted(GMM_SHAPES))
+def test_grouped_matmul_compiles_with_the_roofline_signature(
+        name, one_chip, monkeypatch):
+    """megablox gmm at the tiles ``gmm_tiling`` takes from the shapes
+    compiles for v5e, and is the custom call the benchmark's
+    ``expert_gmm_roofline`` matches in the device trace."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_use_pallas", lambda: "compiled")
+    m, k, n = GMM_SHAPES[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            (((m, k), jnp.bfloat16), ((16, k, n), jnp.bfloat16),
+             ((16,), jnp.int32))]
+    compiled = jax.jit(ops.grouped_matmul).lower(*args).compile()
+    calls = _custom_calls(compiled)
     assert len(calls) == 1
-    assert re.search(_roofline_pattern(), calls[0]), calls[0][:300]
+    assert re.search(_roofline_pattern("expert_gmm_roofline"), calls[0]), \
+        calls[0][:300]
