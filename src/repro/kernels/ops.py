@@ -175,12 +175,14 @@ def ragged_paged_prefill_attention(q, k_pages, v_pages, block_tables,
 def ragged_prefill_update_attend(q, k_new, v_new, k_pages, v_pages,
                                  block_tables, ctx_lens, starts, ends,
                                  row_seq, *, window=None, cap=None,
-                                 scale=None, k_scale=None, v_scale=None):
+                                 scale=None, k_scale=None, v_scale=None,
+                                 trash=0):
     """Fused packed-prefill KV scatter + attention: returns
     ``(o, k_pages, v_pages)``. On the Pallas path the scatter rides inside
     the ragged kernel through aliased page-pool outputs (one launch, no
     separate scatter pass); the XLA path scatters then attends — same pool
-    bytes, same outputs.
+    bytes, same outputs. ``trash``: the block the XLA scatter sends rows
+    owned by no sequence to (the fused write drops them).
 
     Quantized pools: ``k_new``/``v_new`` must arrive *already quantized*
     to the pool dtype and ``k_scale``/``v_scale`` must already contain the
@@ -198,9 +200,9 @@ def ragged_prefill_update_attend(q, k_new, v_new, k_pages, v_pages,
     from repro.models.attention import (ragged_chunk_attention_xla,
                                         update_paged_cache_ragged)
     kc = update_paged_cache_ragged(k_pages, k_new[None], block_tables,
-                                   ctx_lens, starts, ends, row_seq)
+                                   ctx_lens, starts, ends, row_seq, trash)
     vc = update_paged_cache_ragged(v_pages, v_new[None], block_tables,
-                                   ctx_lens, starts, ends, row_seq)
+                                   ctx_lens, starts, ends, row_seq, trash)
     o = ragged_chunk_attention_xla(
         q, kc, vc, block_tables, ctx_lens, starts, ends, row_seq,
         window=window, cap=cap, scale=scale,

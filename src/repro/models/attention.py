@@ -312,28 +312,42 @@ def _constrain_pool(pages):
         pages, jax.sharding.NamedSharding(mesh, POOL_SPEC))
 
 
+def _write_rows(pages, blk, off, new):
+    """Write KV rows into a head-major pool: pool row ``(blk[r], h,
+    off[r])`` of every kv head h takes ``new[r, h]``. One row of hd per
+    (token, kv head), so the scatter's window is that row alone and the
+    pool keeps the layout the attention kernels read: a window across
+    the kv heads makes XLA relayout the whole pool around each write.
+
+    pages: (N, K, block_size, hd); blk, off: (R,); new: (R, K, hd)."""
+    heads = jnp.arange(pages.shape[1], dtype=jnp.int32)[None]
+    return _constrain_pool(pages.at[blk[:, None], heads, off[:, None]].set(
+        new.astype(pages.dtype)))
+
+
 def update_paged_cache(pages, new, block_tables, pos):
     """Scatter one new KV row per sequence into its block-table page.
 
     pages: (num_blocks, K, block_size, hd); new: (B, 1, K, hd); pos: (B,)
-    absolute write position. Inactive serving slots carry an all-zero table
-    row, so their writes land in the reserved trash block 0 (never allocated
-    to a request) and corrupt nothing.
+    absolute write position. Inactive serving slots carry a table row of
+    trash-block ids (block 0, or a layer's own first block in the layer
+    scan's flat pool view), so their writes corrupt nothing.
     """
     bs = pages.shape[2]
     block_ids = jnp.take_along_axis(
         block_tables, (pos // bs)[:, None], axis=1)[:, 0]     # (B,)
-    return _constrain_pool(
-        pages.at[block_ids, :, pos % bs].set(new[:, 0].astype(pages.dtype)))
+    return _write_rows(pages, block_ids, pos % bs, new[:, 0])
 
 
-def update_paged_cache_chunk(pages, new, block_tables, q_start, q_lens):
+def update_paged_cache_chunk(pages, new, block_tables, q_start, q_lens,
+                             trash=0):
     """Scatter a chunk of new KV rows per sequence into its pages.
 
     pages: (num_blocks, K, block_size, hd); new: (B, C, K, hd); q_start:
     (B,) absolute position of chunk row 0; q_lens: (B,) valid rows. Rows
-    past q_lens are routed to the reserved trash block 0 (never allocated
-    to a request), like an idle decode slot's write.
+    past q_lens are routed to the reserved trash block ``trash`` (block 0
+    of a layer's pool, never allocated to a request), like an idle decode
+    slot's write.
     """
     bs = pages.shape[2]
     B, C = new.shape[:2]
@@ -342,14 +356,13 @@ def update_paged_cache_chunk(pages, new, block_tables, q_start, q_lens):
     idx = jnp.clip(pos // bs, 0, nb - 1)
     blk = jnp.take_along_axis(block_tables, idx, axis=1)            # (B, C)
     valid = jnp.arange(C)[None] < q_lens[:, None]
-    blk = jnp.where(valid, blk, 0)                  # trash the padding rows
-    return _constrain_pool(
-        pages.at[blk.reshape(-1), :, (pos % bs).reshape(-1)].set(
-            new.reshape(B * C, *new.shape[2:]).astype(pages.dtype)))
+    blk = jnp.where(valid, blk, trash)              # trash the padding rows
+    return _write_rows(pages, blk.reshape(-1), (pos % bs).reshape(-1),
+                       new.reshape(B * C, *new.shape[2:]))
 
 
 def update_paged_cache_ragged(pages, new, block_tables, ctx_lens, starts,
-                              ends, row_seq):
+                              ends, row_seq, trash=0):
     """Scatter a packed (ragged) multi-sequence chunk of KV into pages.
 
     pages: (num_blocks, K, block_size, hd); new: (1, T, K, hd) — chunks of
@@ -357,8 +370,8 @@ def update_paged_cache_ragged(pages, new, block_tables, ctx_lens, starts,
     [starts[s], ends[s]) and row_seq maps each flat row to its owner. Flat
     row t lands at absolute position ``ctx_lens[s] - (ends[s] - starts[s])
     + (t - starts[s])`` in sequence s's block table. Rows owned by nobody
-    are routed to the reserved trash block 0, exactly like the padding
-    rows of :func:`update_paged_cache_chunk` — same values, same
+    are routed to the reserved trash block ``trash``, exactly like the
+    padding rows of :func:`update_paged_cache_chunk` — same values, same
     destination rows, so the pool contents match the single-chunk path
     bit for bit.
     """
@@ -370,9 +383,8 @@ def update_paged_cache_ragged(pages, new, block_tables, ctx_lens, starts,
     valid = (t >= starts[row_seq]) & (t < ends[row_seq])
     pos = jnp.where(valid, q_start + (t - starts[row_seq]), 0)
     idx = jnp.clip(pos // bs, 0, nb - 1)
-    blk = jnp.where(valid, block_tables[row_seq, idx], 0)
-    return _constrain_pool(
-        pages.at[blk, :, pos % bs].set(new[0].astype(pages.dtype)))
+    blk = jnp.where(valid, block_tables[row_seq, idx], trash)
+    return _write_rows(pages, blk, pos % bs, new[0])
 
 
 def replicate_over_model(x):
@@ -665,7 +677,8 @@ def ragged_chunk_attention(q, k_pages, v_pages, block_tables, ctx_lens,
 def ragged_chunk_update_attend(q, k_new, v_new, k_pages, v_pages,
                                block_tables, ctx_lens, starts, ends,
                                row_seq, *, window=None, cap=None,
-                               scale=None, k_scale=None, v_scale=None):
+                               scale=None, k_scale=None, v_scale=None,
+                               trash=0):
     """Scatter a packed chunk's KV into the pages and attend, fused when
     the backend allows.
 
@@ -681,6 +694,8 @@ def ragged_chunk_update_attend(q, k_new, v_new, k_pages, v_pages,
     materializes — and its scale rows are scattered into the scale pools
     *before* the fused kernel launches (the kernel reads scale pages for
     the dequant). Returns ``(o, k_pages, v_pages, k_scale, v_scale)``.
+    ``trash``: the trash block of the scatters, as in
+    :func:`update_paged_cache_ragged`.
     """
     from repro.kernels import ops as kops
     K = k_pages.shape[1]
@@ -690,19 +705,19 @@ def ragged_chunk_update_attend(q, k_new, v_new, k_pages, v_pages,
         kq, ksr = quant.quantize_kv(k_new, kvd)      # (1,T,K,hd),(1,T,K,1)
         vq, vsr = quant.quantize_kv(v_new, kvd)
         ks = update_paged_cache_ragged(k_scale, ksr, block_tables, ctx_lens,
-                                       starts, ends, row_seq)
+                                       starts, ends, row_seq, trash)
         vs = update_paged_cache_ragged(v_scale, vsr, block_tables, ctx_lens,
-                                       starts, ends, row_seq)
+                                       starts, ends, row_seq, trash)
         if tp == 1:
             o, kc, vc = kops.ragged_prefill_update_attend(
                 q[0], kq[0], vq[0], k_pages, v_pages, block_tables,
                 ctx_lens, starts, ends, row_seq, window=window, cap=cap,
-                scale=scale, k_scale=ks, v_scale=vs)
+                scale=scale, k_scale=ks, v_scale=vs, trash=trash)
             return o[None].astype(q.dtype), kc, vc, ks, vs
         kc = update_paged_cache_ragged(k_pages, kq, block_tables, ctx_lens,
-                                       starts, ends, row_seq)
+                                       starts, ends, row_seq, trash)
         vc = update_paged_cache_ragged(v_pages, vq, block_tables, ctx_lens,
-                                       starts, ends, row_seq)
+                                       starts, ends, row_seq, trash)
         o = ragged_chunk_attention(q, kc, vc, block_tables, ctx_lens,
                                    starts, ends, row_seq, window=window,
                                    cap=cap, scale=scale, k_scale=ks,
@@ -712,12 +727,12 @@ def ragged_chunk_update_attend(q, k_new, v_new, k_pages, v_pages,
         o, kc, vc = kops.ragged_prefill_update_attend(
             q[0], k_new[0], v_new[0], k_pages, v_pages, block_tables,
             ctx_lens, starts, ends, row_seq, window=window, cap=cap,
-            scale=scale)
+            scale=scale, trash=trash)
         return o[None].astype(q.dtype), kc, vc
     kc = update_paged_cache_ragged(k_pages, k_new, block_tables, ctx_lens,
-                                   starts, ends, row_seq)
+                                   starts, ends, row_seq, trash)
     vc = update_paged_cache_ragged(v_pages, v_new, block_tables, ctx_lens,
-                                   starts, ends, row_seq)
+                                   starts, ends, row_seq, trash)
     o = ragged_chunk_attention(q, kc, vc, block_tables, ctx_lens, starts,
                                ends, row_seq, window=window, cap=cap,
                                scale=scale)

@@ -221,18 +221,20 @@ def _attn_chunk_paged(bp, x, cfg: ModelConfig, ctx, cache, kind: str):
         v, vsr = quant.quantize_kv(v, kvd)
         ksc = update_paged_cache_chunk(cache["k_scale"], ksr,
                                        ctx["block_tables"], ctx["q_start"],
-                                       ctx["q_lens"])
+                                       ctx["q_lens"], ctx["trash"])
         vsc = update_paged_cache_chunk(cache["v_scale"], vsr,
                                        ctx["block_tables"], ctx["q_start"],
-                                       ctx["q_lens"])
+                                       ctx["q_lens"], ctx["trash"])
         scales = {"k_scale": ksc, "v_scale": vsc}
     else:
         ksc = vsc = None
         scales = {}
     kc = update_paged_cache_chunk(cache["k"], k, ctx["block_tables"],
-                                  ctx["q_start"], ctx["q_lens"])
+                                  ctx["q_start"], ctx["q_lens"],
+                                  ctx["trash"])
     vc = update_paged_cache_chunk(cache["v"], v, ctx["block_tables"],
-                                  ctx["q_start"], ctx["q_lens"])
+                                  ctx["q_start"], ctx["q_lens"],
+                                  ctx["trash"])
     y = paged_chunk_attention(q, kc, vc, ctx["block_tables"],
                               ctx["ctx_lens"], ctx["q_lens"], window=window,
                               cap=cfg.attn_logit_softcap,
@@ -259,14 +261,14 @@ def _attn_ragged_paged(bp, x, cfg: ModelConfig, ctx, cache, kind: str):
             ctx["ctx_lens"], ctx["starts"], ctx["ends"], ctx["row_seq"],
             window=window, cap=cfg.attn_logit_softcap,
             scale=attention_scale(cfg), k_scale=cache["k_scale"],
-            v_scale=cache["v_scale"])
+            v_scale=cache["v_scale"], trash=ctx["trash"])
         new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
     else:
         y, kc, vc = ragged_chunk_update_attend(
             q, k, v, cache["k"], cache["v"], ctx["block_tables"],
             ctx["ctx_lens"], ctx["starts"], ctx["ends"], ctx["row_seq"],
             window=window, cap=cfg.attn_logit_softcap,
-            scale=attention_scale(cfg))
+            scale=attention_scale(cfg), trash=ctx["trash"])
         new_cache = {"k": kc, "v": vc}
     y = out_proj(bp["attn"], y, x.dtype)
     if cfg.post_block_norm:
@@ -362,6 +364,9 @@ def _default_positions(batch, B, S):
 # ---------------------------------------------------------------------------
 
 
+_PAGED_MODES = ("decode_paged", "chunk_paged", "ragged_paged")
+
+
 def _scan_periods(params, x, cfg: ModelConfig, ctx, mode: str,
                   pcfg: ParallelConfig, cache=None):
     """Scan the period body over NP periods.
@@ -369,11 +374,31 @@ def _scan_periods(params, x, cfg: ModelConfig, ctx, mode: str,
     mode="train":    xs=blocks,         carry=(x, aux), ys=None
     mode="prefill":  xs=blocks,         carry=(x, aux), ys=cache slices
     mode="decode":   xs=(blocks,cache), carry=(x, aux), ys=new cache slices
+    paged modes:     xs=(blocks, slot state, p), carry=(x, aux, pools),
+                     ys=new slot state slices
+
+    In the paged modes the attention page pools ride in the carry, each
+    leaf (NP, N, K, bs, hd) viewed flat as (NP·N, K, bs, hd) (merging the
+    leading dims is a bitcast): period p's layers address their blocks
+    as ``block_tables + p·N``, and their trash block is p·N. The KV
+    writes then update the one pool buffer in place, where pools in
+    xs/ys would be sliced out and stacked back, layer by layer, every
+    step. A hybrid's per-slot mamba state stays in xs/ys.
 
     aux: the experts' load-balancing loss in training; in every serving
     mode of an expert model, the counts of ``moe.moe_block``.
     """
     kinds, NP = period_structure(cfg)
+    paged = mode in _PAGED_MODES
+    pools = {}
+    if paged:
+        # attention stacks are {"k","v"[,scales]} dicts, mamba state tuples
+        pools = {n: c for n, c in cache.items() if isinstance(c, dict)}
+        cache = {n: c for n, c in cache.items() if n not in pools}
+        leaves = jax.tree.leaves(pools)
+        N = leaves[0].shape[1] if leaves else 0    # one num_blocks for all
+        pools = jax.tree.map(lambda t: t.reshape(NP * N, *t.shape[2:]),
+                             pools)
 
     # Megatron-style sequence parallelism: keep the residual stream sharded
     # over "model" on the seq dim between blocks. GSPMD then turns the TP
@@ -394,50 +419,62 @@ def _scan_periods(params, x, cfg: ModelConfig, ctx, mode: str,
             x, NamedSharding(mesh, spec))
 
     def body(carry, xs):
-        x, aux = carry
-        if mode in ("decode", "decode_paged", "chunk_paged", "ragged_paged"):
-            bslices, cslices = xs
+        x, aux, pools = carry
+        lctx = ctx
+        if paged:
+            bslices, state, p = xs
+            caches = {**state, **pools}
+            lctx = dict(ctx, block_tables=ctx["block_tables"] + p * N,
+                        trash=p * N)
+        elif mode == "decode":
+            bslices, caches = xs
         else:
-            bslices, cslices = xs, None
+            bslices, caches = xs, {}
         new_cache = {}
         for i, kind in enumerate(kinds):
-            cc = None if cslices is None else cslices.get(f"sub{i}")
-            x, c, a = _block_apply(kind, bslices[f"sub{i}"], x, cfg, ctx,
-                                   mode, cc)
+            x, c, a = _block_apply(kind, bslices[f"sub{i}"], x, cfg, lctx,
+                                   mode, caches.get(f"sub{i}"))
             aux = aux + a
             if c is not None:
                 new_cache[f"sub{i}"] = c
         if cfg.shared_attn_period:
             sp = params["shared"]
-            cc = None if cslices is None else cslices.get("shared")
+            cc = caches.get("shared")
             if mode == "chunk_paged":
-                x, c = _attn_chunk_paged(sp, x, cfg, ctx, cc, "attn")
+                x, c = _attn_chunk_paged(sp, x, cfg, lctx, cc, "attn")
             elif mode == "decode_paged":
-                x, c = _attn_decode_paged(sp, x, cfg, ctx, cc, "attn")
+                x, c = _attn_decode_paged(sp, x, cfg, lctx, cc, "attn")
             elif mode == "decode":
-                x, c = _attn_decode(sp, x, cfg, ctx, cc, "attn")
+                x, c = _attn_decode(sp, x, cfg, lctx, cc, "attn")
             else:
-                x, c = _attn_full(sp, x, cfg, ctx, "attn")
+                x, c = _attn_full(sp, x, cfg, lctx, "attn")
                 c = c if mode == "prefill" else None
             h = apply_norm(sp["norm2"], x, cfg)
             x = x + apply_mlp(sp["mlp"], h, cfg)
             if c is not None:
                 new_cache["shared"] = c
         x = _sp_constrain(x)
-        return (x, aux), (new_cache if new_cache else None)
+        pools = {n: new_cache.pop(n) for n in pools}
+        return (x, aux, pools), (new_cache if new_cache else None)
 
     if pcfg.remat != "none" and mode == "train":
         policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                   if pcfg.remat == "dots" else None)
         body = jax.checkpoint(body, policy=policy, prevent_cse=False)
 
-    xs = ((params["blocks"], cache)
-          if mode in ("decode", "decode_paged", "chunk_paged", "ragged_paged")
-          else params["blocks"])
+    if paged:
+        xs = (params["blocks"], cache, jnp.arange(NP, dtype=jnp.int32))
+    elif mode == "decode":
+        xs = (params["blocks"], cache)
+    else:
+        xs = params["blocks"]
     # an expert model's serving modes carry its layers' summed counts
     aux0 = (jnp.zeros((2,), jnp.int32) if cfg.moe is not None
             and mode != "train" else jnp.zeros((), jnp.float32))
-    (x, aux), caches = jax.lax.scan(body, (x, aux0), xs)
+    (x, aux, pools), caches = jax.lax.scan(body, (x, aux0, pools), xs)
+    if paged:
+        caches = {**(caches or {}), **jax.tree.map(
+            lambda t: t.reshape(NP, N, *t.shape[1:]), pools)}
     return x, aux, caches
 
 

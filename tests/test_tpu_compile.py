@@ -21,6 +21,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -198,3 +199,155 @@ def test_grouped_matmul_compiles_with_the_roofline_signature(
     assert len(calls) == 1
     assert re.search(_roofline_pattern("expert_gmm_roofline"), calls[0]), \
         calls[0][:300]
+
+
+# The serving step's layer scan carries the KV pools in place (one flat
+# (layers·N, K, bs, hd) view per leaf; models.transformer._scan_periods).
+# Compiled for v5e at published widths, cut in depth, each step program must
+# hold no pool-sized copy, slice or relayout, and its temporaries must not
+# grow with the pool. Each pool leaf is sized past v5e's 128 MiB of VMEM
+# (134 and 268 MB), as every cell's is: a leaf that fits there is staged
+# into VMEM and back by copies that a served pool never sees.
+SCAN_CASES = {"starcoder2_3b": ("starcoder2_3b", 2, (8193, 16385)),
+              "mellum2_12b-ep4": ("mellum2_12b_a2p5b", 4, (8193, 16385))}
+SCAN_B, SCAN_C, SCAN_NB = 16, 256, 256       # the ide cells' batch and chunk
+
+
+def _scan_config(arch, layers):
+    import dataclasses
+    cfg = dataclasses.replace(get_config(arch), num_layers=layers)
+    if cfg.moe is not None:     # one chip's share: 16 of the 64 experts
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, experts_held=16))
+    return cfg
+
+
+def _argmax_sample(logits_d, logits_c, *_):
+    rows = logits_d if logits_c is None else \
+        jnp.concatenate([logits_d, logits_c])
+    return jnp.argmax(rows, axis=-1)
+
+
+def _compile_step(cfg, num_blocks, has_chunk, device):
+    """runner.step as the engine jits it (cache donated), for v5e. Its
+    sampling touches no pool and takes most of the compile time, so an
+    argmax stands in for it."""
+    import functools
+    from types import SimpleNamespace
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.config import ParallelConfig
+    from repro.models import api
+    from repro.serving.engine import InferenceEngine
+    from repro.serving.runners import make_runner
+    from repro.serving.scheduler import StepPlan
+
+    mesh = Mesh(np.array([device]).reshape(1, 1), ("data", "model"))
+    rep = NamedSharding(mesh, PartitionSpec())
+
+    def sds(tree, dtype=None):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, dtype or s.dtype, sharding=rep), tree)
+
+    runner = make_runner(cfg, ParallelConfig(remat="none"))
+    runner._sample = _argmax_sample
+    engine = SimpleNamespace(
+        max_batch=SCAN_B, chunk_width=SCAN_C, max_blocks_per_seq=SCAN_NB,
+        prefill_pack=1, bm=None,
+        samp_buf=SimpleNamespace(vocab_size=cfg.vocab_size))
+    params = sds(api.abstract_params(cfg)[0], jnp.bfloat16)
+    cache = sds(jax.eval_shape(
+        lambda: runner.init_cache(num_blocks, BS, SCAN_B)))
+    arrays = sds(jax.eval_shape(lambda: InferenceEngine._build_arrays(
+        engine, StepPlan([], [], []), False)))
+    fn = jax.jit(functools.partial(runner.step, has_chunk=has_chunk),
+                 donate_argnums=(1,))
+    with jax.set_mesh(mesh):
+        return fn.lower(params, cache, arrays).compile()
+
+
+_DTYPE_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "s8": 1, "u32": 4}
+
+
+def _pool_sized_ops(compiled, min_bytes):
+    """Instructions outside fused computations whose output has a pool's
+    trailing (block_size, head_dim) dims and at least ``min_bytes``, and
+    which do not leave the pool where it is: anything but a parameter,
+    bitcast, tuple access, loop, or a fusion whose root is a scatter (the
+    in-place KV write)."""
+    text = compiled.as_text()
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    roots, computation = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            computation = head.group(1)
+        elif line.lstrip().startswith("ROOT ") and computation in fused:
+            roots[computation] = re.search(r"\} ([\w\-]+)\(", line)
+    scatter = {c for c, m in roots.items() if m and m.group(1) == "scatter"}
+    keep = {"parameter", "bitcast", "get-tuple-element", "tuple", "while"}
+    found, computation = [], None
+    instr = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]"
+                       r"\S* ([\w\-]+)\(")
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            computation = head.group(1)
+            continue
+        m = instr.match(line)
+        if computation in fused or not m:
+            continue
+        name, dtype, dims, op = m.groups()
+        shape = [int(d) for d in dims.split(",")] if dims else []
+        if shape[-2:] != [BS, HD] or op in keep:
+            continue
+        if int(np.prod(shape)) * _DTYPE_BYTES.get(dtype, 4) < min_bytes:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        if op == "fusion" and called and called.group(1) in scatter:
+            continue
+        found.append(f"{op} {name} {dtype}{shape}")
+    return found
+
+
+@pytest.mark.parametrize("has_chunk", [False, True], ids=["decode", "chunk"])
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_layer_scan_keeps_the_pools_in_place(case, has_chunk, one_chip,
+                                             monkeypatch):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_use_pallas", lambda: "compiled")
+    arch, layers, sizes = SCAN_CASES[case]
+    cfg = _scan_config(arch, layers)
+    device = next(iter(one_chip.device_set))
+    # one layer's k pool at the smaller size, and both sizes' whole pools
+    leaf = lambda n: n * cfg.num_kv_heads * BS * cfg.head_dim * 2  # noqa
+    n_stacks = 2 * len(cfg.block_pattern)                  # k, v per kind
+    periods = layers // len(cfg.block_pattern)
+    pools = [leaf(n) * n_stacks * periods for n in sizes]
+    temps, programs = [], []
+    for n in sizes:
+        compiled = _compile_step(cfg, n, has_chunk, device)
+        assert not _pool_sized_ops(compiled, leaf(sizes[0])), \
+            _pool_sized_ops(compiled, leaf(sizes[0]))
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+        programs.append(compiled)
+    assert abs(temps[1] - temps[0]) < 0.01 * (pools[1] - pools[0]), temps
+
+    # the kernels keep the signatures the benchmark's rooflines match: one
+    # decode call per attention layer of a period in each layer scan, one
+    # chunk call too in the chunk's scan, three grouped matmuls per expert
+    # layer in each scan, and no call matches two patterns or a wrong one
+    patterns = {m: _roofline_pattern(m) for m in (
+        "paged_decode_roofline", "paged_prefill_roofline",
+        "expert_gmm_roofline")}
+    scans = 2 if has_chunk else 1
+    per_period = len(cfg.block_pattern)
+    want = {"paged_decode_roofline": per_period,
+            "paged_prefill_roofline": per_period if has_chunk else 0,
+            "expert_gmm_roofline":
+                3 * per_period * scans if cfg.moe is not None else 0}
+    calls = _custom_calls(programs[0])
+    hits = [[m for m, p in patterns.items() if re.search(p, c)]
+            for c in calls]
+    assert all(len(h) <= 1 for h in hits), hits
+    got = {m: sum(h == [m] for h in hits) for m in patterns}
+    assert got == want, (got, want)
